@@ -21,12 +21,7 @@ import jax
 from repro.configs import TrainConfig, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.workloads import default_train_config, make_workload
-from repro.utils.hlo import (
-    collective_bytes,
-    cost_analysis_dict,
-    loop_aware_collective_bytes,
-    peak_memory_bytes,
-)
+from repro.utils.hlo import collective_bytes, loop_aware_collective_bytes
 from repro.utils.roofline import roofline_terms
 from repro.configs.base import INPUT_SHAPE_BY_NAME
 
@@ -45,12 +40,12 @@ def measure(cfg, shape_name, tcfg=None, label="", layout="tp"):
             .lower(*wl["args"]).compile()
         )
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     txt = compiled.as_text()
     res = {
         "arch": cfg.name, "shape": shape_name, "variant": label,
         "compile_s": round(time.perf_counter() - t0, 1),
-        "memory": {"peak_bytes_per_device": peak_memory_bytes(mem),
+        "memory": {"peak_bytes_per_device": mem.peak_memory_in_bytes,
                    "argument_bytes_per_device": int(mem.argument_size_in_bytes)},
         "cost": {"flops": float(cost.get("flops", 0.0)),
                  "bytes_accessed": float(cost.get("bytes accessed", 0.0))},

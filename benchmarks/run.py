@@ -88,6 +88,9 @@ def main() -> None:
 
     import jax
 
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print(
         f"# devices={len(jax.devices())} backend={jax.default_backend()}"
         f"{' SMOKE (numbers are not measurements)' if args.smoke else ''}",

@@ -32,9 +32,11 @@ from repro.core.ppat import PPATConfig
 from repro.core.alignment import csls_retrieval_acc, procrustes
 from repro.kge.data import corrupt_triples, synthesize_universe
 from repro.kge.models import KGEModel, init_kge
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     print(f"devices: {len(jax.devices())}")
     kgs = synthesize_universe(
         seed=0, scale=1 / 400,

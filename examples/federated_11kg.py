@@ -13,9 +13,11 @@ sys.path.insert(0, "src")
 from repro.core.federation import FederationScheduler
 from repro.core.ppat import PPATConfig
 from repro.kge.data import synthesize_universe
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--ticks", type=int, default=3)
     ap.add_argument("--scale", type=float, default=400.0, help="1/scale of Tab. 2")

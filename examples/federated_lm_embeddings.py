@@ -23,6 +23,7 @@ from repro.core.alignment import procrustes
 from repro.core.ppat import PPATConfig, train_ppat
 from repro.data.pipeline import SyntheticTextDataset, make_batches
 from repro.train.step import init_train_state, make_train_step
+from repro.utils.compile_cache import use_compile_cache
 
 
 def train_party(cfg, seed, steps, batch=8, seq=64):
@@ -51,6 +52,7 @@ def eval_loss(cfg, state, ds, seed=99, batches=5, batch=8, seq=64):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--steps", type=int, default=60)

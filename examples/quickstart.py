@@ -13,9 +13,11 @@ sys.path.insert(0, "src")
 from repro.core.federation import FederationScheduler
 from repro.core.ppat import PPATConfig
 from repro.kge.data import synthesize_universe
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     kgs = synthesize_universe(
         seed=0,
         scale=1 / 400,

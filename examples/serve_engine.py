@@ -18,9 +18,11 @@ import numpy as np
 from repro.configs import get_config, reduced
 from repro.models.model import init_params
 from repro.serving.engine import ServingEngine
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--requests", type=int, default=6)

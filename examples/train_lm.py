@@ -19,9 +19,11 @@ from repro.checkpoint import save_checkpoint
 from repro.configs import TrainConfig, get_config
 from repro.data.pipeline import SyntheticTextDataset, make_batches
 from repro.train.step import init_train_state, make_train_step
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

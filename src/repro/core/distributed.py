@@ -24,17 +24,17 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.pate import pate_vote, teacher_votes
 from repro.core.ppat import PPATConfig, _disc_prob, _init_disc, _sgd_momentum
-from repro.sharding.context import auto_axis_types_kw, shard_map_compat
 
 
 def make_party_mesh(n_parties: int = 2) -> Mesh:
     devs = jax.devices()[:n_parties]
     return jax.make_mesh(
-        (n_parties,), ("party",), devices=devs, **auto_axis_types_kw(1)
+        (n_parties,), ("party",), devices=devs, axis_types=(AxisType.Auto,)
     )
 
 
@@ -56,12 +56,18 @@ def make_owner_mesh(n_owners: int) -> Mesh:
             raise ValueError(
                 f"owner mesh of {n_owners} exceeds {len(jax.devices())} devices"
             )
-        mesh = jax.make_mesh(
-            (n_owners,), ("owners",), devices=jax.devices()[:n_owners],
-            **auto_axis_types_kw(1),
-        )
+        mesh = owner_mesh_over(jax.devices()[:n_owners])
         _OWNER_MESHES[n_owners] = mesh
     return mesh
+
+
+def owner_mesh_over(devices: Sequence) -> Mesh:
+    """The ``("owners",)`` mesh over ``devices`` in exactly their order:
+    group assembly puts entry ``k`` on ``devices[k]`` and reads position
+    ``k`` back from mesh slot ``k``. ``jax.make_mesh`` would reorder TPU
+    chips into a physical ring (``[0, 1, 3, 2]`` on a v5e 2x2), which
+    silently hands each entry another owner's result."""
+    return Mesh(np.asarray(devices), ("owners",), axis_types=(AxisType.Auto,))
 
 
 def owner_shard_map(fn, n_owners: int):
@@ -71,9 +77,9 @@ def owner_shard_map(fn, n_owners: int):
     instead of N (the tick engine's trace-time dedup lever). The body sees
     local shards of extent 1 and must keep the leading axis."""
     mesh = make_owner_mesh(n_owners)
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(P("owners"),), out_specs=P("owners"),
-        check=False,
+        check_vma=False,
     )
 
 
@@ -361,12 +367,12 @@ def ppat_exchange_step(mesh: Mesh, cfg: PPATConfig):
             }
             return new_state, metrics, (n0, n1)
 
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             spmd,
             mesh=mesh,
             in_specs=(P(), P("party"), P("party"), P("party")),
             out_specs=(P(), P("party"), P("party")),
-            check=False,
+            check_vma=False,
         )
         return fn(state, xb, yb, key)
 

@@ -425,6 +425,11 @@ class TickEngine:
         #: cache misses (first tick per (owner, version, device)), pinned
         #: flat across steady-state ticks by the transfer-guard test
         self.resident_transfers = 0
+        #: entries launched inside a shard_map ``group`` program vs as a
+        #: ``single`` per-entry program (singletons, ``placement="single"``
+        #: and execution-time group fallbacks) — what the multi-chip smoke
+        #: reads to show sharded placement really ran group programs
+        self.dispatched = {"group": 0, "single": 0}
 
     # ------------------------------------------------------------- caches
     def _resident_on(self, info: Dict, device) -> Dict[str, jnp.ndarray]:
@@ -631,14 +636,13 @@ class TickEngine:
         owners: List[str],
         placement: str,
         residency: str,
-    ) -> Tuple[List[Optional[Dict]], List[Optional[Exception]],
-               List[Optional[Tuple[int, ...]]]]:
+    ) -> Tuple[List[Optional[Dict]], List[Optional[Tuple[int, ...]]]]:
         """Launch every entry program asynchronously; returns
-        ``(outs, errs, groups)`` in plan order: per-entry output pytrees
-        (unmaterialized), per-entry dispatch exceptions, and for each entry
-        the tuple of plan indices sharing its shard_map group output
-        (``None`` for singletons) so the blocking phase can fall back when a
-        group failure only surfaces at execution time.
+        ``(outs, groups)`` in plan order: per-entry output pytrees
+        (unmaterialized), and for each entry the tuple of plan indices
+        sharing its shard_map group output (``None`` for singletons) so the
+        blocking phase can fall back when a group failure only surfaces at
+        execution time.
 
         ``single``: every entry runs its signature's program on the default
         device. ``sharded``: entries are bucketed by signature and ordered
@@ -657,31 +661,28 @@ class TickEngine:
         compile per (signature, chunk extent) — extents restricted to
         ``{devices} ∪ {2^k}`` cap that at ~log₂(devices) per signature.
 
-        Fault isolation: each dispatch unit is wrapped, so one bad entry
-        records a per-entry error instead of aborting the tick, and a
-        shard_map group that fails AT DISPATCH falls back to per-entry
-        execution of its members on their home devices — one poisoned owner
-        never sinks its bucket-mates. Entries whose spec is ``None`` were
+        Nothing here is caught: an entry or group program that fails to
+        trace or compile is a defect of the program, not a fault of an
+        owner, so it raises out of ``run`` (the serial engine does the
+        same). Execution-time failures are isolated per entry by
+        ``execute``'s blocking phase. Entries whose spec is ``None`` were
         already isolated by the fault layer and are skipped."""
         n = len(specs)
         outs: List[Optional[Dict]] = [None] * n
-        errs: List[Optional[Exception]] = [None] * n
         groups: List[Optional[Tuple[int, ...]]] = [None] * n
         devices = jax.devices()
 
         def single(i: int, device) -> None:
-            try:
-                outs[i] = _entry_program(specs[i])(
-                    self._materialize(protos[i], device)
-                )
-            except Exception as ex:  # noqa: BLE001 — isolate, don't abort
-                errs[i] = ex
+            outs[i] = _entry_program(specs[i])(
+                self._materialize(protos[i], device)
+            )
+            self.dispatched["single"] += 1
 
         if placement == "single":
             for i, spec in enumerate(specs):
                 if spec is not None:
                     single(i, devices[0])
-            return outs, errs, groups
+            return outs, groups
 
         from repro.core.distributed import (
             assemble_group,
@@ -714,22 +715,18 @@ class TickEngine:
                     # matter how the rest of the plan is composed
                     single(i, self.placement.device(owners[i]))
                     continue
-                try:
-                    members = [
-                        self._materialize(protos[i], devices[k])
-                        for k, i in enumerate(chunk)
-                    ]
-                    for k in range(real, extent):  # masked dummy tail
-                        members.append(
-                            self._materialize(protos[chunk[-1]], devices[k])
-                        )
-                    out = _group_program(spec, extent)(
-                        assemble_group(members, extent)
+                members = [
+                    self._materialize(protos[i], devices[k])
+                    for k, i in enumerate(chunk)
+                ]
+                for k in range(real, extent):  # masked dummy tail
+                    members.append(
+                        self._materialize(protos[chunk[-1]], devices[k])
                     )
-                except Exception:  # noqa: BLE001 — group fallback
-                    for i in chunk:
-                        single(i, self.placement.device(owners[i]))
-                    continue
+                out = _group_program(spec, extent)(
+                    assemble_group(members, extent)
+                )
+                self.dispatched["group"] += real
                 # dummy-position outputs are simply never read
                 for shard, i in zip(disassemble_group(out, extent), chunk):
                     outs[i] = shard
@@ -738,7 +735,7 @@ class TickEngine:
             # legacy behavior: stage every result back to the default device
             # (None is an empty pytree node, so failed slots pass through)
             outs = jax.device_put(outs, devices[0])
-        return outs, errs, groups
+        return outs, groups
 
     def execute(
         self,
@@ -764,8 +761,9 @@ class TickEngine:
         exactly the rows the serial gathers read, and straggles add their
         simulated delay to the entry's measured wall-clock, tripping
         ``deadline`` — late results are discarded through the normal
-        backtrack restore and the handshake deferred. One failing entry
-        never aborts the tick.
+        backtrack restore and the handshake deferred. One entry failing
+        while it executes never aborts the tick; a program that fails to
+        trace or compile raises out of it.
 
         ``adversary`` (a ``core.adversary.Adversary``, default ``None`` =
         the bit-identical pre-attack path) tampers client views at the same
@@ -940,13 +938,14 @@ class TickEngine:
             specs[i] = EntrySpec(**kw)
             protos[i] = (mut, res)
 
-        outs, errs, groups = self._dispatch(
+        outs, groups = self._dispatch(
             specs, protos, owners, placement, residency
         )
         # block per entry so one failing program poisons one entry, not the
         # tick; a shard_map group whose failure only surfaces at execution
         # time is re-dispatched per-entry on the members' home devices (the
         # group's healthy owners still land their results)
+        errs: List[Optional[Exception]] = [None] * n
         retried: set = set()
         for i in range(n):
             if outs[i] is None or errs[i] is not None:
@@ -967,6 +966,7 @@ class TickEngine:
                                 protos[j], self.placement.device(owners[j])
                             )
                         )
+                        self.dispatched["single"] += 1
                     except Exception as e2:  # noqa: BLE001
                         outs[j], errs[j] = None, e2
                 if errs[i] is None:
@@ -985,7 +985,7 @@ class TickEngine:
                 continue
             spec, out = specs[i], outs[i]
             if out is None or errs[i] is not None:
-                # an uninjected exception the dispatch/blocking phase
+                # an uninjected execution-time exception the blocking phase
                 # isolated: same failure path as a crash, attributed to the
                 # host, kind "error"
                 sched._entry_failed(

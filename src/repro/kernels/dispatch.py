@@ -62,21 +62,19 @@ SPARSE_KERNEL_FAMILIES = ("transe", "distmult")
 def resolve_train_impl(impl: Optional[str] = None, family: str = "transe") -> str:
     """Pick the training-engine step implementation.
 
-    ``pallas`` — the fused gather→score→scatter sparse_update kernel
-    (TransE/DistMult only; its serial in-kernel scatter relies on the single
-    grid step executing sequentially, which holds everywhere, but the
-    dynamic-slice row loop only lowers well on TPU); ``xla`` — the autodiff
-    sparse step (every family, every backend; one compiled scan on CPU CI);
-    ``reference`` — the seed dense host-loop path, kept as the parity oracle.
-    ``REPRO_TRAIN_IMPL`` overrides."""
+    ``xla`` — the autodiff sparse step (every family, every backend) — is
+    the default everywhere. ``pallas`` — the fused gather→score→scatter
+    sparse_update kernel (TransE/DistMult only) — runs only when asked for,
+    in interpret mode: Mosaic cannot lower its in-kernel row scatters
+    (``acc.at[i, :].set``) or vector gathers at any table size, so the TPU
+    compiler refuses it (pinned by ``tests/test_tpu_compile.py``) until the
+    kernel is rewritten with tables in ``pl.ANY``, row DMA and ids in SMEM.
+    ``reference`` — the seed dense host-loop path, kept as the parity
+    oracle. ``REPRO_TRAIN_IMPL`` overrides."""
     if impl is None:
         impl = os.environ.get("REPRO_TRAIN_IMPL", "").strip().lower() or None
     if impl is None:
-        impl = (
-            "pallas"
-            if jax.default_backend() == "tpu" and family in SPARSE_KERNEL_FAMILIES
-            else "xla"
-        )
+        impl = "xla"
     if impl not in ("pallas", "xla", "reference"):
         raise ValueError(f"unknown train impl {impl!r} (pallas|xla|reference)")
     if impl == "pallas" and family not in SPARSE_KERNEL_FAMILIES:
@@ -288,18 +286,30 @@ def resolve_serve_replicas(n: Optional[int] = None) -> int:
     return n
 
 
-def resolve_rank_impl(impl: Optional[str] = None) -> str:
+#: score modes the fused-rank kernel lowers for on TPU. ``cl1`` (RotatE)
+#: slices each row into [re | im] halves, which Mosaic refuses ("Shape
+#: mismatch in input, indices and output"; pinned by
+#: ``tests/test_tpu_compile.py``).
+RANK_KERNEL_MODES = ("l1", "l2", "dot")
+
+
+def resolve_rank_impl(impl: Optional[str] = None, mode: str = "l1") -> str:
     """Pick the fused-rank engine implementation: ``pallas`` or ``xla``.
 
     The fused-rank kernel revisits its output block across the entity grid
     axis (``index_map`` ignores j), which is only sound where grid steps run
     sequentially — TPU. On GPU the Triton grid is parallel, so auto picks the
     ``xla`` scan there too; ``REPRO_RANK_IMPL=pallas`` can force it for
-    experimentation."""
+    experimentation. Modes the kernel does not lower for
+    (``RANK_KERNEL_MODES``) always take the ``xla`` scan, the way
+    ``resolve_train_impl`` keeps families the training kernel does not cover
+    off it."""
     if impl is None:
         impl = os.environ.get("REPRO_RANK_IMPL", "").strip().lower() or None
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl not in ("pallas", "xla"):
         raise ValueError(f"unknown rank-engine impl {impl!r} (pallas|xla)")
+    if impl == "pallas" and mode not in RANK_KERNEL_MODES:
+        impl = "xla"
     return impl

@@ -162,7 +162,7 @@ def fused_ranks_graph(
     embed rank counting inside a larger compiled program (the federation tick
     engine). Resolves the implementation exactly like ``fused_ranks``."""
     assert mode in SCORE_MODES, mode
-    impl = resolve_rank_impl(impl)
+    impl = resolve_rank_impl(impl, mode)
     if impl == "pallas":
         return fused_ranks_pallas_graph(
             q, ent, gold, filt, mode=mode, block_q=block_q, block_e=block_e,
@@ -190,7 +190,7 @@ def fused_ranks(
     tile-computed score of the same entity.
     """
     assert mode in SCORE_MODES, mode
-    impl = resolve_rank_impl(impl)
+    impl = resolve_rank_impl(impl, mode)
     if impl == "pallas":
         return _fused_ranks_pallas(
             q, ent, gold, filt, mode=mode, block_q=block_q, block_e=block_e,

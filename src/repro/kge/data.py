@@ -15,6 +15,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 # (name, #relations, #entities, #triples) — Tab. 2 of the paper.
@@ -85,6 +87,43 @@ class KG:
         return idx_self.astype(np.int32), idx_other.astype(np.int32)
 
 
+@jax.jit
+def _nearest_blocks(tgt, hid, ent_z):
+    ent_sq = jnp.sum(ent_z**2, axis=1)
+
+    def block(args):
+        t, h = args
+        d = (
+            jnp.sum(t**2, axis=1)[:, None]
+            - 2 * jnp.dot(t, ent_z.T, precision=jax.lax.Precision.HIGHEST)
+            + ent_sq[None]
+        )
+        d = d.at[jnp.arange(t.shape[0]), h].set(jnp.inf)  # no self-loop
+        return jnp.argmin(d, axis=1)
+
+    return jax.lax.map(block, (tgt, hid))
+
+
+def _nearest_tails(
+    target: np.ndarray, h_idx: np.ndarray, ent_z: np.ndarray,
+    *, block_elems: int = 1 << 26,
+) -> np.ndarray:
+    """argmin_e ‖target_i − ent_z[e]‖² over e ≠ h_idx[i], as int64 ids.
+
+    An exact scan over every (query, entity) pair: 10¹² pairs at the paper's
+    full scale, so it runs blockwise on the default JAX device (one compiled
+    ``lax.map`` over row blocks of at most ``block_elems`` distances).
+    Different backends may break fp near-ties differently."""
+    n, e = len(target), len(ent_z)
+    rows = max(1, min(n, block_elems // max(1, e)))
+    nb = -(-n // rows)
+    pad = nb * rows - n
+    tgt = np.pad(target, ((0, pad), (0, 0))).reshape(nb, rows, -1)
+    hid = np.pad(h_idx, (0, pad)).reshape(nb, rows).astype(np.int32)
+    out = _nearest_blocks(jnp.asarray(tgt), jnp.asarray(hid), jnp.asarray(ent_z))
+    return np.asarray(out).reshape(-1)[:n].astype(np.int64)
+
+
 def synthesize_universe(
     *,
     seed: int = 0,
@@ -94,6 +133,7 @@ def synthesize_universe(
     alignments: Optional[List[Tuple[str, str, int]]] = None,
     noise: float = 0.05,
     density_boost: float = 8.0,
+    exact: bool = False,
 ) -> Dict[str, KG]:
     """Build the 11-KG universe mirroring Tab. 2 / Tab. 3 at ``scale``.
 
@@ -102,6 +142,14 @@ def synthesize_universe(
     sparse for any KGE model to generalize (loss→0, test accuracy ~chance —
     pure memorization), so scaled KGs keep the paper's *relative* sizes but
     are denser. Recorded as a deviation in EXPERIMENTS.md.
+
+    By default the latent universe holds 0.8 × the owners' entity total, so
+    owners also overlap by chance beyond the listed alignments, and an owner
+    whose private fill runs past the universe comes out smaller than its
+    row. ``exact=True`` sizes the universe to the owners' disjoint need
+    instead: every owner gets exactly its row's entity count and every pair
+    exactly its listed alignment — what a deployment sized from a published
+    source needs.
     """
     rng = np.random.default_rng(seed)
     kg_stats = kg_stats or PAPER_KG_STATS
@@ -116,7 +164,14 @@ def synthesize_universe(
         for n, r, e, t in kg_stats
     }
 
-    total_universe = int(sum(e for _, e, _ in sizes.values()) * 0.8)
+    shared = [
+        min(sc(n_al, 2), int(0.6 * min(sizes[a][1], sizes[b][1])))
+        for a, b, n_al in alignments
+    ]
+    n_ent_total = sum(e for _, e, _ in sizes.values())
+    total_universe = (
+        n_ent_total - sum(shared) if exact else int(n_ent_total * 0.8)
+    )
     z = rng.normal(0, 1.0, (total_universe, latent_dim)).astype(np.float32)
 
     # global relation pool with translational latents
@@ -137,13 +192,10 @@ def synthesize_universe(
             out = np.concatenate([out, rng.choice(total_universe, k - len(out))])
         return out
 
-    for a, b, n_al in alignments:
-        n_al = sc(n_al, 2)
-        cap = min(sizes[a][1], sizes[b][1])
-        n_al = min(n_al, int(0.6 * cap))
-        shared = take(n_al)
-        assigned[a].update(shared.tolist())
-        assigned[b].update(shared.tolist())
+    for (a, b, _), n_al in zip(alignments, shared):
+        ids = take(n_al).tolist()
+        assigned[a].update(ids)
+        assigned[b].update(ids)
 
     rel_cursor = 0
     kgs: Dict[str, KG] = {}
@@ -164,18 +216,7 @@ def synthesize_universe(
         r_idx = rng.integers(0, n_rel, n_tri)
         target = z[ids[h_idx]] + rel_z[rel_ids[r_idx]]
         target += rng.normal(0, noise, target.shape).astype(np.float32)
-        ent_z = z[ids]  # (E, L)
-        t_idx = np.empty(n_tri, dtype=np.int64)
-        step = max(1, 2_000_000 // max(1, n_ent))
-        for s in range(0, n_tri, step):
-            blk = target[s : s + step]
-            d = (
-                np.sum(blk**2, axis=1)[:, None]
-                - 2 * blk @ ent_z.T
-                + np.sum(ent_z**2, axis=1)[None]
-            )
-            d[np.arange(len(blk)), h_idx[s : s + step]] = np.inf  # no self-loop
-            t_idx[s : s + step] = np.argmin(d, axis=1)
+        t_idx = _nearest_tails(target, h_idx, z[ids])
         triples = np.stack([h_idx, r_idx, t_idx], axis=1).astype(np.int32)
         triples = np.unique(triples, axis=0)
 

@@ -248,21 +248,24 @@ def lp_gold_scores(q: jnp.ndarray, ent: jnp.ndarray, idx: jnp.ndarray, mode: str
     return -jnp.sum(jnp.abs(q - e), axis=-1)
 
 
-def _use_score_kernel(via_kernel: bool | None) -> bool:
+def _use_score_kernel(via_kernel: bool | None, mode: str) -> bool:
     if via_kernel is not None:
         return via_kernel
-    from repro.kernels.dispatch import COMPILED_BACKENDS
+    from repro.kernels.dispatch import COMPILED_BACKENDS, RANK_KERNEL_MODES
 
     # compiled Pallas backends route through the tiled kernel (write-once
-    # tiles — safe on TPU and GPU); CPU CI keeps the numerically-identical
-    # jnp broadcast (interpret mode would be slower)
-    return jax.default_backend() in COMPILED_BACKENDS
+    # tiles — safe on TPU and GPU) for the modes it lowers; CPU CI keeps the
+    # numerically-identical jnp broadcast (interpret mode would be slower)
+    return (
+        jax.default_backend() in COMPILED_BACKENDS
+        and mode in RANK_KERNEL_MODES
+    )
 
 
 def _decomposed_scores(q, table, mode: str, m: KGEModel, via_kernel):
     """(B, d) query × (E, d) table → (B, E) through the tile kernel on
     compiled backends, or the numerically-identical jnp broadcast on CPU."""
-    if _use_score_kernel(via_kernel):
+    if _use_score_kernel(via_kernel, mode):
         from repro.kernels.triple_score import pairwise_scores
 
         return pairwise_scores(q, table, mode=mode)

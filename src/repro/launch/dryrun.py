@@ -31,12 +31,7 @@ from repro.configs.base import INPUT_SHAPES
 from repro.configs.registry import ARCHS, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.workloads import make_workload, supported
-from repro.utils.hlo import (
-    collective_bytes,
-    cost_analysis_dict,
-    loop_aware_collective_bytes,
-    peak_memory_bytes,
-)
+from repro.utils.hlo import collective_bytes, loop_aware_collective_bytes
 from repro.utils.roofline import roofline_terms
 
 
@@ -65,7 +60,7 @@ def dryrun_one(
         t_compile = time.perf_counter() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     coll = collective_bytes(hlo_text)
     coll_corrected = loop_aware_collective_bytes(hlo_text)
@@ -82,7 +77,7 @@ def dryrun_one(
             "argument_bytes_per_device": int(mem.argument_size_in_bytes),
             "output_bytes_per_device": int(mem.output_size_in_bytes),
             "temp_bytes_per_device": int(mem.temp_size_in_bytes),
-            "peak_bytes_per_device": peak_memory_bytes(mem),
+            "peak_bytes_per_device": mem.peak_memory_in_bytes,
         },
         "cost": {
             "flops": float(cost.get("flops", 0.0)),

@@ -7,9 +7,7 @@ dry-run entrypoint sets the 512-device host-platform flag).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.sharding.context import auto_axis_types_kw
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -17,11 +15,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: (2, 16, 16) = 512 chips ('pod', 'data', 'model')."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over whatever local devices exist (tests, examples)."""
     return jax.make_mesh(
-        (data, model), ("data", "model"), **auto_axis_types_kw(2)
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
     )

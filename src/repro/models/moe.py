@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import dtype_of
-from repro.sharding.context import shard_map_compat
 
 
 def init_moe(key, cfg, d: int) -> dict:
@@ -331,7 +330,7 @@ def apply_moe_alltoall(
             router, w_gate, w_up, w_down, shared_tuple if shared_tuple else None, xl
         )
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         wrapper,
         mesh=mesh,
         in_specs=(
@@ -343,7 +342,7 @@ def apply_moe_alltoall(
             batch_spec,                   # x
         ),
         out_specs=(batch_spec, P()),
-        check=False,
+        check_vma=False,
     )
     return fn(
         params["router"], params["w_gate"], params["w_up"], params["w_down"],

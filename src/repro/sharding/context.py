@@ -8,32 +8,9 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 from jax.sharding import Mesh
 
 _MESH: Optional[Mesh] = None
-
-
-def auto_axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto,)*n`` kwargs for ``jax.make_mesh`` on jax
-    versions that have ``jax.sharding.AxisType`` (> 0.4.37); empty dict (the
-    same Auto default) on older versions."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` (new API, ``check_vma=``) with a fallback to
-    ``jax.experimental.shard_map`` (``check_rep=``) on jax ≤ 0.4.37."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
 
 
 def set_mesh(mesh: Optional[Mesh]) -> None:

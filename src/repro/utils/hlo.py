@@ -154,25 +154,3 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     out["total"] = sum(v for k, v in out.items() if k in _COLLECTIVES)
     out["count"] = count
     return dict(out)
-
-
-def peak_memory_bytes(mem) -> int:
-    """``CompiledMemoryStats.peak_memory_in_bytes`` with a jax-0.4.x fallback
-    (argument + output + temp — the upper bound XLA reports pieces of)."""
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if peak is not None:
-        return int(peak)
-    return int(
-        mem.argument_size_in_bytes
-        + mem.output_size_in_bytes
-        + mem.temp_size_in_bytes
-    )
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as one flat dict — jax 0.4.x returns a
-    one-element list of per-program dicts, newer jax the dict itself."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)

@@ -33,8 +33,8 @@ def test_small_mesh_lower_compile_and_collectives():
         from repro.utils.hlo import collective_bytes
 
         cfg = reduced(get_config("qwen3-0.6b"), vocab=2048)
-        from repro.sharding.context import auto_axis_types_kw
-        mesh = jax.make_mesh((2, 4), ("data", "model"), **auto_axis_types_kw(2))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         tcfg = TrainConfig(global_batch=8, seq_len=64, microbatches=2, ce_chunk=0)
         state = jax.eval_shape(lambda k: init_train_state(k, cfg),
                                jax.ShapeDtypeStruct((2,), jnp.uint32))
@@ -49,10 +49,9 @@ def test_small_mesh_lower_compile_and_collectives():
                               out_shardings=(sspec, None)).lower(state, batch)
             compiled = lowered.compile()
         coll = collective_bytes(compiled.as_text())
-        from repro.utils.hlo import peak_memory_bytes
         mem = compiled.memory_analysis()
         print(json.dumps({"total": coll["total"], "count": coll["count"],
-                          "peak": peak_memory_bytes(mem)}))
+                          "peak": mem.peak_memory_in_bytes}))
         """
     )
     data = json.loads(out.strip().splitlines()[-1])
@@ -135,8 +134,8 @@ def test_moe_alltoall_matches_gather():
         from repro.models.moe import init_moe, apply_moe_gather, apply_moe_alltoall
         from repro.sharding import context as shard_ctx
 
-        from repro.sharding.context import auto_axis_types_kw
-        mesh = jax.make_mesh((4, 2), ("data", "model"), **auto_axis_types_kw(2))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shard_ctx.set_mesh(mesh)
         cfg = reduced(get_config("kimi-k2-1t-a32b")).replace(dtype="float32")
         cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))

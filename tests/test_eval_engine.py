@@ -19,6 +19,7 @@ from repro.kernels.triple_score import (
     pairwise_scores,
     pairwise_scores_ref,
 )
+from repro.kernels.triple_score.ops import fused_ranks_pallas_graph
 from repro.kge.data import synthesize_universe
 from repro.kge.eval import (
     best_threshold_accuracy,
@@ -64,11 +65,17 @@ def test_fused_ranks_matches_ref(b, e, d, block_e, impl, mode):
     scores = pairwise_scores_ref(q, ent, mode=mode)
     gold = scores[jnp.arange(b), jnp.asarray(gold_idx)]
     ref = np.asarray(fused_ranks_ref(q, ent, gold, jnp.asarray(filt), mode=mode))
-    out = np.asarray(
-        fused_ranks(q, ent, gold, jnp.asarray(filt), mode=mode,
-                    block_e=block_e, impl=impl, interpret=True)
-    )
-    np.testing.assert_array_equal(out, ref)
+    if impl == "pallas":
+        # the kernel itself, interpreted: dispatch keeps cl1 off it (it does
+        # not lower on TPU), but its tile math stays pinned for every mode
+        out = fused_ranks_pallas_graph(
+            q, ent, gold, jnp.asarray(filt), mode=mode, block_q=8,
+            block_e=block_e, interpret=True,
+        )
+    else:
+        out = fused_ranks(q, ent, gold, jnp.asarray(filt), mode=mode,
+                          block_e=block_e, impl=impl)
+    np.testing.assert_array_equal(np.asarray(out), ref)
 
 
 @pytest.mark.parametrize("mode", ["l1", "l2", "dot", "cl1"])
@@ -177,6 +184,10 @@ def test_resolve_rank_impl(monkeypatch):
     assert resolve_rank_impl(None) in ("pallas", "xla")
     monkeypatch.setenv("REPRO_RANK_IMPL", "pallas")
     assert resolve_rank_impl(None) == "pallas"
+    # RotatE's half-width tiles do not lower on TPU: always the scan
+    assert resolve_rank_impl(None, "cl1") == "xla"
+    assert resolve_rank_impl("pallas", "cl1") == "xla"
+    assert resolve_rank_impl("pallas", "dot") == "pallas"
     with pytest.raises(ValueError):
         resolve_rank_impl("tensorflow")
 

@@ -122,3 +122,28 @@ def test_universe_alignment_consistency(small_kgs):
     ia, ib = a.aligned_with(b)
     assert len(ia) > 30
     assert (a.universe_ids[ia] == b.universe_ids[ib]).all()
+
+
+def test_exact_universe_keeps_listed_sizes():
+    stats = [("A", 10, 80000, 280000), ("B", 8, 60000, 200000)]
+    kgs = synthesize_universe(seed=0, scale=1 / 400, kg_stats=stats,
+                              alignments=[("A", "B", 4000)], exact=True)
+    assert kgs["A"].num_entities == 200 and kgs["B"].num_entities == 150
+    # 340 distinct entities: more than the default 0.8 × 350 universe holds
+    assert len(kgs["A"].aligned_with(kgs["B"])[0]) == 10
+
+
+@pytest.mark.parametrize("block_elems", [1 << 26, 3 * 97])
+def test_nearest_tails_matches_numpy_scan(block_elems):
+    """The blockwise device scan picks the tail the plain NumPy scan picks,
+    for one block and for several with a padded tail block."""
+    from repro.kge.data import _nearest_tails
+
+    rng = np.random.default_rng(0)
+    ent = rng.normal(size=(97, 12)).astype(np.float32)
+    h = rng.integers(0, 97, 50)
+    target = ent[h] + rng.normal(0, 0.6, (50, 12)).astype(np.float32)
+    d = ((target[:, None, :].astype(np.float64) - ent[None]) ** 2).sum(-1)
+    d[np.arange(50), h] = np.inf  # no self-loop
+    got = _nearest_tails(target, h, ent, block_elems=block_elems)
+    np.testing.assert_array_equal(got, d.argmin(axis=1))

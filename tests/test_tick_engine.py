@@ -187,6 +187,33 @@ def test_batched_tick_rejects_reference_train_impl(universe, monkeypatch):
         assert list(fed.queue[n]) == queues_before[n]
 
 
+def test_entry_trace_failure_propagates(universe, monkeypatch):
+    """An entry program that cannot be traced or compiled is a defect of
+    the program, not an owner's fault: it raises out of ``run`` instead of
+    being isolated as a ``fault="error"`` event, and the planned offers go
+    back to their queues."""
+    import repro.core.tick_engine as tick_engine
+
+    class TraceError(Exception):
+        pass
+
+    def broken_graph(inp, spec):
+        raise TraceError("entry graph failed to trace")
+
+    fed = _make(universe)
+    fed.initial_training()
+    queues_before = {n: list(fed.queue[n]) for n in universe}
+    monkeypatch.setattr(tick_engine, "_ENTRY_PROGRAMS", {})
+    monkeypatch.setattr(tick_engine, "entry_graph", broken_graph)
+    with pytest.raises(TraceError):
+        fed.run(max_ticks=1, tick_impl="batched", tick_placement="single")
+    assert not [e for e in fed.events if e.fault is not None]
+    assert all(e.kind == "init" for e in fed.events)
+    for n in universe:
+        assert fed.state[n] is NodeState.READY
+        assert list(fed.queue[n]) == queues_before[n]
+
+
 def test_equal_shaped_owners_share_one_entry_program():
     """Trace-time program dedup: N equal-shaped owners must compile exactly
     ONE tick-entry program per tick kind (per unique entry signature), not

@@ -243,7 +243,11 @@ def test_train_epochs_device_roundtrip_shapes():
 
 
 # --------------------------------------------------------- dispatch + retrace
-def test_resolve_train_impl():
+def test_resolve_train_impl(monkeypatch):
+    monkeypatch.delenv("REPRO_TRAIN_IMPL", raising=False)
+    # the autodiff step on every backend: the kernel does not lower on TPU
+    assert resolve_train_impl(None, "transe") == "xla"
+    assert resolve_train_impl(None, "distmult") == "xla"
     assert resolve_train_impl("reference") == "reference"
     assert resolve_train_impl("xla", "transh") == "xla"
     # the kernel only covers the decomposable hot path → fall back
