@@ -58,6 +58,18 @@ first batch ever) pays no compile: programs specialize on shape, not
 version, so each ``(kind, bucket, replica)`` signature warms exactly once
 per process.
 
+**Observability** — the serving thread's work is marked with
+``jax.profiler.TraceAnnotation`` leaf spans, which never overlap and cost
+about a microsecond each with no profiler active: ``repro.serve.submit``
+(validation and enqueue), ``.coalesce`` (popping the batch, shedding
+included), ``.assemble`` (re-validation, concatenation, filter rows,
+padding), ``.put`` (``device_put`` of the batch), ``.launch`` (the program
+dispatch), ``.collect`` (outputs to host, scattered into requests) and
+``.wait`` (polling while the dispatch-ahead window is full). ``put``,
+``launch`` and ``collect`` carry the launch ``seq`` that a served
+request's ``batch`` names. ``stats`` counts why each batched coalesce
+stopped: ``cut_kind``, ``cut_full``, ``cut_empty``.
+
 ``serve_impl="direct"`` (``REPRO_SERVE_IMPL``) disables coalescing — one
 dispatch per request, the baseline ``bench_serving.py`` measures batching
 against. ``REPRO_SERVE_REPLICAS`` sizes the replica ring.
@@ -75,6 +87,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.distributed import replica_devices
 from repro.core.faults import ServeFault, ServeFaultError, ServeFaultPlan
@@ -116,7 +129,12 @@ class QueryRequest:
     # perf_counter: latency math (finished_at - submitted_at) must be
     # monotonic; time.time() jumps with NTP/clock adjustments
     submitted_at: float = field(default_factory=time.perf_counter)
+    #: when its batch was first launched (retries and hedges keep it):
+    #: ``dispatched_at - submitted_at`` is its wait in the FIFO,
+    #: ``finished_at - dispatched_at`` its time from dispatch to result
+    dispatched_at: Optional[float] = None
     finished_at: Optional[float] = None
+    batch: Optional[int] = None     # launch seq that served it
     version: Optional[int] = None   # table version that served it
     result: object = None
     error: Optional[Exception] = None
@@ -262,7 +280,11 @@ class KGEServingTier:
             "rejected": 0, "retried": 0, "hedged": 0,
             "breaker_open": 0, "breaker_close": 0,
             "batches": 0, "published": 0, "publish_errors": 0,
-            "padded_rows": 0, "warmed": 0,
+            "warmed": 0,
+            # why a batched coalesce stopped growing, one count per batch:
+            # the next request was of the other kind or top-k bucket, would
+            # pass max_batch, or the queue ran out
+            "cut_kind": 0, "cut_full": 0, "cut_empty": 0,
         }
         #: bucket specs to pre-trace at publish: ("rank", rows) or
         #: ("topk", rows, k). Rows/k are rounded to the same pow-2 buckets
@@ -443,37 +465,39 @@ class KGEServingTier:
         """Queue a filtered-rank query batch; returns immediately.
         ``deadline`` is this request's queue budget in seconds — expired
         requests are shed at coalesce time instead of dispatched."""
-        self._admit()
-        tv = self._active
-        h = check_id_range("head entity", h, self.model.num_entities)
-        t = check_id_range("tail entity", t, self.model.num_entities)
-        r = check_id_range("relation", r, self.model.num_relations)
-        tv.check_finite("entity", tv.ent_bad, h)
-        tv.check_finite("relation", tv.rel_bad, r)
-        rid = self._next_rid
-        self._next_rid += 1
-        return self._submit(
-            QueryRequest(rid, "rank", h, r, t, deadline=deadline)
-        )
+        with TraceAnnotation("repro.serve.submit", rid=self._next_rid):
+            self._admit()
+            tv = self._active
+            h = check_id_range("head entity", h, self.model.num_entities)
+            t = check_id_range("tail entity", t, self.model.num_entities)
+            r = check_id_range("relation", r, self.model.num_relations)
+            tv.check_finite("entity", tv.ent_bad, h)
+            tv.check_finite("relation", tv.rel_bad, r)
+            rid = self._next_rid
+            self._next_rid += 1
+            return self._submit(
+                QueryRequest(rid, "rank", h, r, t, deadline=deadline)
+            )
 
     def submit_topk(self, h, r, *, k: int = 10,
                     deadline: Optional[float] = None) -> QueryRequest:
         """Queue a top-k candidate query batch; returns immediately."""
-        self._admit()
-        tv = self._active
-        h = check_id_range("head entity", h, self.model.num_entities)
-        r = check_id_range("relation", r, self.model.num_relations)
-        if not 1 <= k <= self.model.num_entities:
-            raise ValueError(
-                f"k must be in [1, {self.model.num_entities}], got {k}"
+        with TraceAnnotation("repro.serve.submit", rid=self._next_rid):
+            self._admit()
+            tv = self._active
+            h = check_id_range("head entity", h, self.model.num_entities)
+            r = check_id_range("relation", r, self.model.num_relations)
+            if not 1 <= k <= self.model.num_entities:
+                raise ValueError(
+                    f"k must be in [1, {self.model.num_entities}], got {k}"
+                )
+            tv.check_finite("entity", tv.ent_bad, h)
+            tv.check_finite("relation", tv.rel_bad, r)
+            rid = self._next_rid
+            self._next_rid += 1
+            return self._submit(
+                QueryRequest(rid, "topk", h, r, k=int(k), deadline=deadline)
             )
-        tv.check_finite("entity", tv.ent_bad, h)
-        tv.check_finite("relation", tv.rel_bad, r)
-        rid = self._next_rid
-        self._next_rid += 1
-        return self._submit(
-            QueryRequest(rid, "topk", h, r, k=int(k), deadline=deadline)
-        )
 
     # ------------------------------------------------------ admission loop
     def _shed(self, req: QueryRequest, now: float) -> None:
@@ -492,9 +516,11 @@ class KGEServingTier:
 
     def _coalesce(self) -> List[QueryRequest]:
         """Pop the FIFO head's batchable prefix: same kind (and same top-k
-        bucket), up to ``max_batch`` query rows. Deadline-expired requests
-        are shed (popped, never dispatched) as they surface. ``direct``
-        mode takes one request — the per-call baseline."""
+        bucket), up to ``max_batch`` query rows, counting in ``stats`` why
+        the batch stopped (``cut_kind`` / ``cut_full`` / ``cut_empty``).
+        Deadline-expired requests are shed (popped, never dispatched) as
+        they surface. ``direct`` mode takes one request — the per-call
+        baseline."""
         now = time.perf_counter()
         while self.queue and self._expired(self.queue[0], now):
             self._shed(self.queue.popleft(), now)
@@ -506,19 +532,22 @@ class KGEServingTier:
             return take
         rows = len(head.h)
         kb = _pow2_at_least(head.k) if head.kind == "topk" else 0
-        while self.queue and rows < self.max_batch:
+        cut = "cut_empty"
+        while self.queue:
             nxt = self.queue[0]
             if self._expired(nxt, now):
                 self._shed(self.queue.popleft(), now)
                 continue
-            if nxt.kind != head.kind:
-                break
-            if head.kind == "topk" and _pow2_at_least(nxt.k) != kb:
+            if nxt.kind != head.kind or (
+                    head.kind == "topk" and _pow2_at_least(nxt.k) != kb):
+                cut = "cut_kind"
                 break
             if rows + len(nxt.h) > self.max_batch:
+                cut = "cut_full"
                 break
             take.append(self.queue.popleft())
             rows += len(nxt.h)
+        self.stats[cut] += 1
         return take
 
     def _pad(self, arrs: List[np.ndarray], nq: int) -> List[np.ndarray]:
@@ -529,7 +558,6 @@ class KGEServingTier:
                             else 1)
         if nb == nq:
             return arrs
-        self.stats["padded_rows"] += nb - nq
         return [
             np.concatenate([a, np.repeat(a[:1], nb - nq, axis=0)], axis=0)
             for a in arrs
@@ -622,31 +650,35 @@ class KGEServingTier:
 
     def _dispatch(self, reqs: List[QueryRequest]) -> int:
         tv = self._active  # ONE read: the batch is pinned to this version
-        reqs = self._revalidate(reqs, tv)
-        if not reqs:
-            return 0
         kind = reqs[0].kind
-        h = np.concatenate([q.h for q in reqs])
-        r = np.concatenate([q.r for q in reqs])
-        nq = len(h)
-        segs, off = [], 0
-        for q in reqs:
-            segs.append((q, off, len(q.h)))
-            off += len(q.h)
-        if kind == "rank":
-            t = np.concatenate([q.t for q in reqs])
-            filt = np.concatenate(
-                [t[:, None].astype(np.int32), self.filters.rows_for(h, r)],
-                axis=1,
-            )
-            host_in = tuple(self._pad([h, r, t, filt], nq))
-            kb = 0
-        else:
-            kb = min(_pow2_at_least(reqs[0].k), self.model.num_entities)
-            filt = self.filters.rows_for(h, r)
-            host_in = tuple(self._pad([h, r, filt], nq))
+        with TraceAnnotation("repro.serve.assemble", kind=kind) as span:
+            reqs = self._revalidate(reqs, tv)
+            if not reqs:
+                return 0
+            h = np.concatenate([q.h for q in reqs])
+            r = np.concatenate([q.r for q in reqs])
+            nq = len(h)
+            span.set_metadata(rows=nq)
+            segs, off = [], 0
+            for q in reqs:
+                segs.append((q, off, len(q.h)))
+                off += len(q.h)
+            if kind == "rank":
+                t = np.concatenate([q.t for q in reqs])
+                filt = np.concatenate(
+                    [t[:, None].astype(np.int32), self.filters.rows_for(h, r)],
+                    axis=1,
+                )
+                host_in = tuple(self._pad([h, r, t, filt], nq))
+                kb = 0
+            else:
+                kb = min(_pow2_at_least(reqs[0].k), self.model.num_entities)
+                filt = self.filters.rows_for(h, r)
+                host_in = tuple(self._pad([h, r, filt], nq))
         self.stats["batches"] += 1
-        self._launch(kind, host_in, segs, nq, tv, kb)
+        fl = self._launch(kind, host_in, segs, nq, tv, kb)
+        for q in reqs:
+            q.dispatched_at = fl.dispatched_at
         return nq
 
     def _launch(self, kind: str, host_in: Tuple, segs, nq: int,
@@ -666,32 +698,36 @@ class KGEServingTier:
                 self.fault_counts[fault.kind] = (
                     self.fault_counts.get(fault.kind, 0) + 1
                 )
-        ptab = tv.on(rep.device)
-        if kind == "rank":
-            dh, dr, dt, df = jax.device_put(host_in, rep.device)
-            counts = side_counts_dispatch(
-                ptab, self.model, dh, dr, dt, df, side="tail",
-                block_e=self.block_e, impl=self.rank_impl,
-            )
-            out: Tuple = (counts,)
-        else:
-            from repro.serving.engine import (
-                _streaming_topk_decomposed,
-                _streaming_topk_generic,
-            )
-
-            dh, dr, df = jax.device_put(host_in, rep.device)
-            qd = lp_query_tails(ptab, self.model, dh, dr)
-            if qd is not None:
-                q, table, mode = qd
-                vals, ids = _streaming_topk_decomposed(
-                    q, table, df, k=kb, block_e=self.block_e, mode=mode
+        with TraceAnnotation("repro.serve.put", seq=seq):
+            dev_in = jax.device_put(host_in, rep.device)
+        with TraceAnnotation("repro.serve.launch", seq=seq, replica=rep.slot):
+            ptab = tv.on(rep.device)
+            if kind == "rank":
+                dh, dr, dt, df = dev_in
+                counts = side_counts_dispatch(
+                    ptab, self.model, dh, dr, dt, df, side="tail",
+                    block_e=self.block_e, impl=self.rank_impl,
                 )
+                out: Tuple = (counts,)
             else:
-                vals, ids = _streaming_topk_generic(
-                    ptab, self.model, dh, dr, df, k=kb, block_e=self.block_e
+                from repro.serving.engine import (
+                    _streaming_topk_decomposed,
+                    _streaming_topk_generic,
                 )
-            out = (vals, ids)
+
+                dh, dr, df = dev_in
+                qd = lp_query_tails(ptab, self.model, dh, dr)
+                if qd is not None:
+                    q, table, mode = qd
+                    vals, ids = _streaming_topk_decomposed(
+                        q, table, df, k=kb, block_e=self.block_e, mode=mode
+                    )
+                else:
+                    vals, ids = _streaming_topk_generic(
+                        ptab, self.model, dh, dr, df, k=kb,
+                        block_e=self.block_e,
+                    )
+                out = (vals, ids)
         rep.inflight += 1
         rep.dispatched += 1
         fl = _InFlight(
@@ -703,21 +739,25 @@ class KGEServingTier:
             self.inflight.append(fl)
         return fl
 
+    def _hedge_due(self, b: _InFlight) -> bool:
+        """``b`` has been in flight longer than ``hedge_after`` seconds, is
+        not hedged yet, and a second replica exists to hedge onto."""
+        return (
+            self.hedge_after is not None and b.hedge is None
+            and time.perf_counter() - b.dispatched_at >= self.hedge_after
+            and not b.ready()
+            and any(rp is not b.replica for rp in self.replicas)
+        )
+
     def _maybe_hedge(self) -> None:
         """Hedged dispatch of the oldest stuck batch: if the FIFO head has
         been in flight longer than ``hedge_after`` seconds, launch a
         duplicate on a DIFFERENT replica and let the first result win —
         bit-identical either way, since both replicas hold the batch's
         pinned ``TableVersion``."""
-        if self.hedge_after is None or not self.inflight:
+        if not self.inflight or not self._hedge_due(self.inflight[0]):
             return
         b = self.inflight[0]
-        if b.hedge is not None or b.ready():
-            return
-        if time.perf_counter() - b.dispatched_at < self.hedge_after:
-            return
-        if all(rp is b.replica for rp in self.replicas):
-            return  # no second replica to hedge onto
         b.hedge = self._launch(
             b.kind, b.host_in, b.segs, b.nq, b.tv, b.kb,
             attempts=b.attempts, exclude=(b.replica,), hedge_of=b,
@@ -771,6 +811,17 @@ class KGEServingTier:
         hedge), zombie the loser, and on total failure either re-dispatch
         to a different replica (failure isolation — the batch's requests
         survive) or, past ``retry_limit``, fail its requests."""
+        with TraceAnnotation("repro.serve.collect", seq=b.seq):
+            failed = self._settle(b)
+        if failed is not None:
+            self.stats["retried"] += 1
+            self._launch(b.kind, b.host_in, b.segs, b.nq, b.tv, b.kb,
+                         attempts=b.attempts + 1, exclude=failed)
+
+    def _settle(self, b: _InFlight) -> Optional[Tuple[Replica, ...]]:
+        """Collect ``b``'s outputs and scatter them into its requests, or
+        fail them; returns the replicas that failed it when a retry is
+        due instead."""
         sources = (
             [b] if b.hedge is None
             else ([b, b.hedge] if b.ready() else [b.hedge, b])
@@ -790,17 +841,13 @@ class KGEServingTier:
                 self._note_failure(src.replica)
                 spent.append(src)
         if host is None:
-            failed = tuple(s.replica for s in spent)
             if b.attempts < self.retry_limit:
-                self.stats["retried"] += 1
-                self._launch(b.kind, b.host_in, b.segs, b.nq, b.tv, b.kb,
-                             attempts=b.attempts + 1, exclude=failed)
-                return
+                return tuple(s.replica for s in spent)
             now = time.perf_counter()
             for q, _, _ in b.segs:
                 q.error, q.done, q.finished_at = err, True, now
             self.stats["failed"] += len(b.segs)
-            return
+            return None
         now = time.perf_counter()
         used.replica.inflight -= 1
         self._note_success(used.replica, now - used.dispatched_at)
@@ -814,9 +861,11 @@ class KGEServingTier:
                 vals, ids = host
                 q.result = (ids[off:off + n, :q.k], vals[off:off + n, :q.k])
             q.version = b.tv.version
+            q.batch = used.seq
             q.finished_at = now
             q.done = True
         self.stats["served"] += len(b.segs)
+        return None
 
     def _reap_zombies(self) -> None:
         if not self._zombies:
@@ -849,7 +898,10 @@ class KGEServingTier:
                 if not block:
                     break
                 self._maybe_hedge()
-                time.sleep(2e-4)
+                with TraceAnnotation("repro.serve.wait"):
+                    while not (self._batch_ready(head)
+                               or self._hedge_due(head)):
+                        time.sleep(2e-4)
                 continue
             block = False
             b = self.inflight.popleft()
@@ -869,7 +921,9 @@ class KGEServingTier:
             return 0
         while len(self.inflight) >= self.max_inflight:
             self._reap(block=True)
-        reqs = self._coalesce()
+        with TraceAnnotation("repro.serve.coalesce") as span:
+            reqs = self._coalesce()
+            span.set_metadata(rows=sum(len(q.h) for q in reqs))
         if not reqs:
             return 0  # everything at the head was shed
         return self._dispatch(reqs)

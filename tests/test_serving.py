@@ -422,3 +422,125 @@ def test_resolve_serve_knobs(monkeypatch):
     assert resolve_serve_replicas(1) == 1  # explicit wins
     with pytest.raises(ValueError, match="replicas must be >= 1"):
         resolve_serve_replicas(0)
+
+
+@pytest.mark.parametrize(
+    "impl, fifo, expect",
+    [
+        # rank, top-k, rank: each batch stops at the other kind, the last
+        # at the empty queue
+        ("batched", [("rank", 2), ("topk", 2), ("rank", 2)],
+         {"cut_kind": 2, "cut_full": 0, "cut_empty": 1}),
+        # four 3-row ranks under max_batch 8: 3 + 3, then 3 would pass it
+        ("batched", [("rank", 3)] * 4,
+         {"cut_kind": 0, "cut_full": 1, "cut_empty": 1}),
+        ("batched", [("topk", 1)], {"cut_kind": 0, "cut_full": 0, "cut_empty": 1}),
+        ("direct", [("rank", 2), ("topk", 2)],
+         {"cut_kind": 0, "cut_full": 0, "cut_empty": 0}),
+    ],
+    ids=["kind", "full", "empty", "direct"],
+)
+def test_tier_coalesce_cut_counters(kge_world, impl, fifo, expect):
+    m, params, known = kge_world
+    tier = KGEServingTier(params, m, known, block_e=64, max_batch=8,
+                          serve_impl=impl)
+    for i, (kind, n) in enumerate(fifo):
+        q = _tri(n, seed=200 + i)
+        if kind == "rank":
+            tier.submit_rank(q[:, 0], q[:, 1], q[:, 2])
+        else:
+            tier.submit_topk(q[:, 0], q[:, 1], k=5)
+    tier.run_until_drained()
+    cuts = {k: tier.stats[k] for k in expect}
+    assert cuts == expect
+    # every batched coalesce counts exactly once, a direct one never
+    assert sum(cuts.values()) == (tier.stats["batches"] if impl == "batched" else 0)
+
+
+@pytest.mark.parametrize("fault", [None, "crash", "straggle"])
+def test_tier_request_dispatch_time_and_batch(kge_world, monkeypatch, fault):
+    from repro.core.faults import ServeFault, ServeFaultPlan
+
+    m, params, known = kge_world
+    dev = jax.devices()[0]
+    # launch seq 0 on slot 0 crashes (retried) or straggles 30 s (hedged)
+    plan = fault and ServeFaultPlan(table={(0, 0): ServeFault(fault, delay=30.0)})
+    tier = KGEServingTier(params, m, known, block_e=64, max_batch=8,
+                          replicas=2, devices=[dev, dev], serve_faults=plan,
+                          hedge_after=0.01 if fault == "straggle" else None)
+    launches = []
+    real = tier._launch
+
+    def spy(*a, **kw):
+        fl = real(*a, **kw)
+        launches.append(fl)
+        return fl
+
+    monkeypatch.setattr(tier, "_launch", spy)
+    reqs = []
+    for i, n in enumerate((2, 3, 1, 4)):
+        q = _tri(n, seed=210 + i)
+        reqs.append(tier.submit_rank(q[:, 0], q[:, 1], q[:, 2]))
+    q = _tri(2, seed=220)
+    reqs.append(tier.submit_topk(q[:, 0], q[:, 1], k=5))
+    tier.run_until_drained()
+    assert tier.stats["retried"] == (fault == "crash")
+    assert tier.stats["hedged"] == (fault == "straggle")
+    assert tier.stats["failed"] == 0
+    for req in reqs:
+        assert req.state == "served"
+        assert req.submitted_at <= req.dispatched_at <= req.finished_at
+        mine = [fl for fl in launches if any(q is req for q, _, _ in fl.segs)]
+        # the first launch set dispatched_at; the last one served it
+        assert req.dispatched_at == mine[0].dispatched_at
+        assert req.batch == mine[-1].seq
+    tried = [fl.seq for fl in launches if any(q is reqs[0] for q, _, _ in fl.segs)]
+    assert tried[0] == 0 and len(tried) == 1 + bool(fault)
+
+
+def test_tier_spans_in_order(kge_world, monkeypatch):
+    from repro.serving import tier as tier_mod
+
+    m, params, known = kge_world
+    tier = KGEServingTier(params, m, known, block_e=64, max_batch=8)
+    events = []
+
+    class Recorder:
+        def __init__(self, name, **meta):
+            self.name, self.meta = name, dict(meta)
+
+        def __enter__(self):
+            events.append(("enter", self.name, self))
+            return self
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name, self))
+            return False
+
+        def set_metadata(self, **meta):
+            self.meta.update(meta)
+
+    monkeypatch.setattr(tier_mod, "TraceAnnotation", Recorder)
+    # the batch is not ready at the first poll, so the reap waits once
+    real_ready, polls = tier_mod._InFlight.ready, []
+
+    def ready(self):
+        polls.append(1)
+        return len(polls) > 1 and real_ready(self)
+
+    monkeypatch.setattr(tier_mod._InFlight, "ready", ready)
+    q = _tri(3, seed=230)
+    req = tier.submit_rank(q[:, 0], q[:, 1], q[:, 2])
+    tier.run_until_drained()
+    assert req.state == "served"
+    names = [n for kind, n, _ in events if kind == "enter"]
+    assert names == ["repro.serve." + s for s in (
+        "submit", "coalesce", "assemble", "put", "launch", "wait", "collect")]
+    # leaf spans: each one closes before the next opens
+    assert [k for k, _, _ in events] == ["enter", "exit"] * len(names)
+    meta = {n[len("repro.serve."):]: sp.meta for k, n, sp in events if k == "enter"}
+    assert meta["submit"] == {"rid": req.rid}
+    assert meta["coalesce"] == {"rows": 3}
+    assert meta["assemble"] == {"kind": "rank", "rows": 3}
+    assert meta["put"] == meta["collect"] == {"seq": req.batch}
+    assert meta["launch"] == {"seq": req.batch, "replica": 0}
