@@ -12,7 +12,6 @@ slot's length are masked by its position).
 """
 from __future__ import annotations
 
-import functools
 import time
 from dataclasses import dataclass, field
 from typing import List, Optional
@@ -123,30 +122,17 @@ class KGECandidateRanker:
     def topk_tails(self, h, r, k: int = 10, *, exclude_known: bool = True):
         """Top-k candidate tails for (h, r, ·) queries → (ids, scores), each
         (B, k). Streams the entity table blockwise with a carried top-k."""
-        from repro.kge.models import lp_query_tails
-
         h_np = self._check_ids("head entity", h, self.model.num_entities)
         r_np = self._check_ids("relation", r, self.model.num_relations)
         self._check_query(h_np, r_np)
-        h = jnp.asarray(h_np)
-        r = jnp.asarray(r_np)
-        b = h.shape[0]
         if exclude_known:
             filt = self.filters.rows_for(h_np, r_np)
         else:
-            filt = np.full((b, 1), -1, np.int32)
-
-        qd = lp_query_tails(self.params, self.model, h, r)
-        if qd is not None:
-            q, table, mode = qd
-            vals, ids = _streaming_topk_decomposed(
-                q, table, jnp.asarray(filt), k=k, block_e=self.block_e, mode=mode
-            )
-        else:
-            vals, ids = _streaming_topk_generic(
-                self.params, self.model, h, r, jnp.asarray(filt),
-                k=k, block_e=self.block_e,
-            )
+            filt = np.full((h_np.shape[0], 1), -1, np.int32)
+        vals, ids = _streaming_topk_jit(
+            self.params, self.model, jnp.asarray(h_np), jnp.asarray(r_np),
+            jnp.asarray(filt), k=k, block_e=self.block_e,
+        )
         return np.asarray(ids), np.asarray(vals)
 
 
@@ -176,35 +162,42 @@ def _topk_scan(score_block, b, e, filt, *, k, block_e):
     return vals, ids
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_e", "mode"))
-def _streaming_topk_decomposed(q, table, filt, *, k, block_e, mode):
+def streaming_topk_graph(params, model, h, r, filt, *, k, block_e):
+    """Filtered streaming top-k tails as a pure graph: the query projection
+    (``lp_query_tails``) is traced into the same program as the blockwise
+    scan, so a top-k dispatch is one jitted call with no eager gathers ahead
+    of it — the top-k counterpart of ``kge.eval.side_counts_graph``.
+    Families that decompose score (query, table block) tiles; the rest
+    (TransH/R/D) score every (h, r, candidate) triple of a block."""
+    from repro.kge.models import lp_query_tails, score_triples
     from repro.kernels.triple_score.triple_score import _tile_scores
 
-    e = table.shape[0]
-
-    def score_block(cb):
-        eb = table[jnp.clip(cb, 0, e - 1)]
-        return _tile_scores(q.astype(jnp.float32), eb.astype(jnp.float32), mode)
-
-    return _topk_scan(score_block, q.shape[0], e, filt, k=k, block_e=block_e)
-
-
-@functools.partial(jax.jit, static_argnames=("model", "k", "block_e"))
-def _streaming_topk_generic(params, model, h, r, filt, *, k, block_e):
-    from repro.kge.models import score_triples
-
     b = h.shape[0]
-    e = model.num_entities
+    qd = lp_query_tails(params, model, h, r)
+    if qd is not None:
+        q, table, mode = qd
+        e = table.shape[0]
 
-    def score_block(cb):
-        ids = jnp.clip(cb, 0, e - 1)
-        be = ids.shape[0]
-        hh = jnp.repeat(h[:, None], be, axis=1).reshape(-1)
-        rr = jnp.repeat(r[:, None], be, axis=1).reshape(-1)
-        tt = jnp.tile(ids[None], (b, 1)).reshape(-1)
-        return score_triples(params, model, hh, rr, tt).reshape(b, be)
+        def score_block(cb):
+            eb = table[jnp.clip(cb, 0, e - 1)]
+            return _tile_scores(q.astype(jnp.float32), eb.astype(jnp.float32), mode)
+    else:
+        e = model.num_entities
+
+        def score_block(cb):
+            ids = jnp.clip(cb, 0, e - 1)
+            be = ids.shape[0]
+            hh = jnp.repeat(h[:, None], be, axis=1).reshape(-1)
+            rr = jnp.repeat(r[:, None], be, axis=1).reshape(-1)
+            tt = jnp.tile(ids[None], (b, 1)).reshape(-1)
+            return score_triples(params, model, hh, rr, tt).reshape(b, be)
 
     return _topk_scan(score_block, b, e, filt, k=k, block_e=block_e)
+
+
+_streaming_topk_jit = jax.jit(
+    streaming_topk_graph, static_argnames=("model", "k", "block_e")
+)
 
 
 @dataclass

@@ -97,7 +97,7 @@ from repro.kernels.dispatch import (
     resolve_serve_replicas,
 )
 from repro.kge.eval import side_counts_dispatch
-from repro.kge.models import lp_query_tails
+from repro.serving.engine import _streaming_topk_jit
 from repro.serving.tables import FilterPack, TableVersion, check_id_range
 
 
@@ -384,24 +384,11 @@ class KGEServingTier:
                         block_e=self.block_e, impl=self.rank_impl,
                     )
                 else:
-                    from repro.serving.engine import (
-                        _streaming_topk_decomposed,
-                        _streaming_topk_generic,
-                    )
-
                     dh, dr, df = jax.device_put((h, r, filt), rep.device)
-                    qd = lp_query_tails(ptab, self.model, dh, dr)
-                    if qd is not None:
-                        q, table, mode = qd
-                        _streaming_topk_decomposed(
-                            q, table, df, k=kb, block_e=self.block_e,
-                            mode=mode,
-                        )
-                    else:
-                        _streaming_topk_generic(
-                            ptab, self.model, dh, dr, df, k=kb,
-                            block_e=self.block_e,
-                        )
+                    _streaming_topk_jit(
+                        ptab, self.model, dh, dr, df, k=kb,
+                        block_e=self.block_e,
+                    )
                 self._warmed.add(sig)
                 self.stats["warmed"] += 1
 
@@ -710,24 +697,10 @@ class KGEServingTier:
                 )
                 out: Tuple = (counts,)
             else:
-                from repro.serving.engine import (
-                    _streaming_topk_decomposed,
-                    _streaming_topk_generic,
-                )
-
                 dh, dr, df = dev_in
-                qd = lp_query_tails(ptab, self.model, dh, dr)
-                if qd is not None:
-                    q, table, mode = qd
-                    vals, ids = _streaming_topk_decomposed(
-                        q, table, df, k=kb, block_e=self.block_e, mode=mode
-                    )
-                else:
-                    vals, ids = _streaming_topk_generic(
-                        ptab, self.model, dh, dr, df, k=kb,
-                        block_e=self.block_e,
-                    )
-                out = (vals, ids)
+                out = _streaming_topk_jit(
+                    ptab, self.model, dh, dr, df, k=kb, block_e=self.block_e,
+                )
         rep.inflight += 1
         rep.dispatched += 1
         fl = _InFlight(
@@ -977,18 +950,10 @@ class KGEServingTier:
 
 def serving_program_cache_size() -> int:
     """Number of compiled serving-program specializations (rank counts +
-    both top-k variants). The retrace-pin test asserts this stays flat
-    across steady-state traffic of ANY mix of batch sizes within the
-    bucket set — continuous batching is only a win if padded buckets
-    actually stop recompilation."""
+    top-k). The retrace-pin test asserts this stays flat across
+    steady-state traffic of ANY mix of batch sizes within the bucket set —
+    continuous batching is only a win if padded buckets actually stop
+    recompilation."""
     from repro.kge.eval import _side_counts_jit
-    from repro.serving.engine import (
-        _streaming_topk_decomposed,
-        _streaming_topk_generic,
-    )
 
-    return sum(
-        p._cache_size()
-        for p in (_side_counts_jit, _streaming_topk_decomposed,
-                  _streaming_topk_generic)
-    )
+    return _side_counts_jit._cache_size() + _streaming_topk_jit._cache_size()

@@ -24,7 +24,7 @@ from repro.serving import (
     KGEServingTier,
     serving_program_cache_size,
 )
-from repro.serving.engine import ServingEngine
+from repro.serving.engine import ServingEngine, _streaming_topk_jit
 
 
 @pytest.fixture(scope="module")
@@ -238,26 +238,42 @@ def test_tier_direct_impl_is_per_request(kge_world):
         )
 
 
-def test_tier_program_cache_pinned_across_traffic_mixes(kge_world):
+def test_tier_program_cache_pinned_across_traffic_mixes(kge_world, monkeypatch):
     m, params, known = kge_world
-    tier = KGEServingTier(params, m, known, block_e=64, max_batch=16)
+    # a block size no other test uses, so every bucket compiles here
+    tier = KGEServingTier(params, m, known, block_e=72, max_batch=16)
+    launched = set()
+    launch = tier._launch
+
+    def record(kind, host_in, segs, nq, tv, kb, **kw):
+        launched.add((kind, host_in[0].shape[0], kb))
+        return launch(kind, host_in, segs, nq, tv, kb, **kw)
+
+    monkeypatch.setattr(tier, "_launch", record)
+    cold, cold_topk = serving_program_cache_size(), _streaming_topk_jit._cache_size()
     # warm every bucket the tier can emit for this traffic envelope
     for i, n in enumerate((1, 3, 8, 16, 11)):
         q = _tri(n, seed=40 + i)
         tier.submit_rank(q[:, 0], q[:, 1], q[:, 2])
-    for i, n in enumerate((2, 5)):
+    for i, (n, k) in enumerate(((2, 5), (5, 5), (9, 5), (3, 9))):
         q = _tri(n, seed=50 + i)
-        tier.submit_topk(q[:, 0], q[:, 1], k=5)
-    tier.run_until_drained()
+        tier.submit_topk(q[:, 0], q[:, 1], k=k)
+        tier.run_until_drained()  # one batch each
     warm = serving_program_cache_size()
+    # each (kind, rows bucket, k bucket) added exactly one program, and the
+    # top-k buckets each one top-k program
+    topk = {s for s in launched if s[0] == "topk"}
+    assert {s[1:] for s in topk} == {(8, 8), (16, 8), (8, 16)}
+    assert warm - cold == len(launched)
+    assert _streaming_topk_jit._cache_size() - cold_topk == len(topk)
     # a different mix of sizes within the same bucket envelope (rank batches
     # pad to 16, topk batches to 8) must not retrace
     for i, n in enumerate((2, 7, 13, 1, 16, 4, 9)):
         q = _tri(n, seed=60 + i)
         tier.submit_rank(q[:, 0], q[:, 1], q[:, 2])
-    for i, n in enumerate((1, 4, 2)):
+    for i, (n, k) in enumerate(((1, 5), (4, 5), (2, 5), (12, 6), (2, 16))):
         q = _tri(n, seed=70 + i)
-        tier.submit_topk(q[:, 0], q[:, 1], k=5)
+        tier.submit_topk(q[:, 0], q[:, 1], k=k)
     tier.run_until_drained()
     assert serving_program_cache_size() == warm
     assert tier.stats["failed"] == 0
