@@ -286,11 +286,13 @@ def resolve_serve_replicas(n: Optional[int] = None) -> int:
     return n
 
 
-#: score modes the fused-rank kernel lowers for on TPU. ``cl1`` (RotatE)
-#: slices each row into [re | im] halves, which Mosaic refuses ("Shape
-#: mismatch in input, indices and output"; pinned by
-#: ``tests/test_tpu_compile.py``).
-RANK_KERNEL_MODES = ("l1", "l2", "dot")
+#: score modes the fused-rank kernel lowers for on TPU (``cl1``, RotatE, on
+#: the lane-aligned [re | im] planes its wrapper lays out)
+RANK_KERNEL_MODES = ("l1", "l2", "dot", "cl1")
+#: score modes the pairwise (B, E) score kernel lowers for: its ``cl1`` tile
+#: slices each row into half-width [re | im] lane slices, which Mosaic
+#: refuses ("Shape mismatch in input, indices and output")
+PAIRWISE_KERNEL_MODES = ("l1", "l2", "dot")
 
 
 def resolve_rank_impl(impl: Optional[str] = None, mode: str = "l1") -> str:

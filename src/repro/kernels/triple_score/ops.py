@@ -21,6 +21,8 @@ from repro.kernels.dispatch import resolve_interpret, resolve_rank_impl
 from repro.kernels.triple_score.triple_score import (
     SCORE_MODES,
     _tile_scores,
+    cl1_block_e,
+    cl1_planes,
     fused_rank_fwd,
     pairwise_scores_fwd,
 )
@@ -87,19 +89,28 @@ def fused_ranks_pallas_graph(
 ) -> jnp.ndarray:
     b, d = q.shape
     e = ent.shape[0]
-    be = min(block_e, e)
+    be = cl1_block_e(block_e, d, e) if mode == "cl1" else min(block_e, e)
     bq = min(block_q, b)
     pad_e = (-e) % be
     pad_b = (-b) % bq
-    if pad_e:
-        ent = jnp.pad(ent, ((0, pad_e), (0, 0)))
+    if mode == "cl1":
+        # the pad to a block multiple, as below, is the call's one
+        # table-sized copy: here it also lays out the [re | im] planes
+        with jax.named_scope("rank_planes"):
+            ent = cl1_planes(ent, e + pad_e)
+            q = cl1_planes(q, b + pad_b)
+    else:
+        if pad_e:
+            ent = jnp.pad(ent, ((0, pad_e), (0, 0)))
+        if pad_b:
+            q = jnp.pad(q, ((0, pad_b), (0, 0)))
     if pad_b:
-        q = jnp.pad(q, ((0, pad_b), (0, 0)))
         gold = jnp.pad(gold, (0, pad_b))
         filt = jnp.pad(filt, ((0, pad_b), (0, 0)), constant_values=-1)
     out = fused_rank_fwd(
         q, ent, gold[:, None].astype(jnp.float32), filt.astype(jnp.int32),
         mode=mode, num_entities=e, block_q=bq, block_e=be, interpret=interpret,
+        half=d // 2,
     )
     return out[:b, 0]
 

@@ -20,6 +20,16 @@ Two kernels share the tile math:
     revisited across the entity grid axis (index_map ignores j), with filter
     exclusion applied in-kernel from a padded known-true index tensor. The
     (B, E) matrix never exists anywhere.
+
+The fused-rank kernel's ``cl1`` (RotatE) path takes its rows as lane-aligned
+planes (``cl1_planes``): the re and im halves each padded to a multiple of
+128 lanes, so no row is sliced inside a lane tile (Mosaic refuses the
+half-width slices of ``_tile_scores``). A grid step scores its entity block
+``CL1_ROWS`` rows at a time and reduces the d/2 components in 128-lane
+chunks, accumulated before one lane reduction: the (Bq, Be, d/2) difference
+tensor never exists, and the padded lanes add exactly 0. The entity block
+is sized from the row width (``cl1_block_e``) so that its two pipeline
+buffers fit scoped VMEM.
 """
 from __future__ import annotations
 
@@ -120,13 +130,109 @@ def _fused_rank_kernel(
 
     # global entity ids of this tile's columns; ids ≥ num_entities are padding
     col = j * block_e + jax.lax.broadcasted_iota(jnp.int32, (bq, be), 1)
+    beats = _beats(s, col, g_ref, f_ref, num_entities)
+    o_ref[...] += jnp.sum(beats.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _beats(s, col, g_ref, f_ref, num_entities: int):
+    """(Bq, n) bool: column ``col`` is a real entity, outside the query's
+    known-true ids, that scores above the query's gold score."""
     valid = col < num_entities
     # in-kernel filter: exclude every known-true id listed for each query
     filt = f_ref[...]  # (Bq, F) int32
-    excl = jnp.any(filt[:, :, None] == col[:, None, :], axis=1)  # (Bq, Be)
+    excl = jnp.any(filt[:, :, None] == col[:, None, :], axis=1)  # (Bq, n)
+    return (s > g_ref[...]) & valid & jnp.logical_not(excl)
 
-    beats = (s > g_ref[...]) & valid & jnp.logical_not(excl)
-    o_ref[...] += jnp.sum(beats.astype(jnp.int32), axis=1, keepdims=True)
+
+#: lanes of one vector register; each cl1 plane is padded to a multiple
+LANES = 128
+#: entity rows a cl1 grid step scores at a time inside its block
+CL1_ROWS = 128
+#: bytes of one cl1 entity block; the pipeline holds two
+CL1_BLOCK_BYTES = 4 << 20
+
+
+def cl1_plane_width(dim: int) -> int:
+    """Lanes of a row of cl1 planes: [re | im], each half padded to 128."""
+    return 2 * pl.cdiv(dim // 2, LANES) * LANES
+
+
+def cl1_block_e(block_e: int, dim: int, n: int) -> int:
+    """The cl1 entity block for ``n`` entities: at most ``block_e`` rows and
+    ``CL1_BLOCK_BYTES`` of planes, and a multiple of ``CL1_ROWS`` when it
+    holds more than that (a table smaller than the block is padded up to
+    whole chunks, never cut to its own length)."""
+    cap = min(block_e, CL1_BLOCK_BYTES // (4 * cl1_plane_width(dim)))
+    if min(cap, n) <= CL1_ROWS:
+        return min(cap, n)
+    return min(cap // CL1_ROWS, pl.cdiv(n, CL1_ROWS)) * CL1_ROWS
+
+
+def cl1_planes(x: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """(n, d) [re | im] rows → (rows, cl1_plane_width(d)) float32 planes:
+    rows past n and lanes past d/2 in each half are zero. Built as a select
+    between two pads of the whole row (re at lane 0, im shifted to the
+    second plane), which XLA fuses into one pass over the table; slicing the
+    halves first costs a second pass."""
+    n, d = x.shape
+    half, w = d // 2, cl1_plane_width(d) // 2
+    x = x.astype(jnp.float32)
+    re = jnp.pad(x, ((0, rows - n), (0, 2 * w - d)))
+    im = jnp.pad(x, ((0, rows - n), (w - half, w - half)))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * w), 1)
+    return jnp.where(lane < half, re,
+                     jnp.where((lane >= w) & (lane < w + half), im, 0.0))
+
+
+def _cl1_plane_scores(q_ref, e_ref, r0, rows: int, half: int) -> jnp.ndarray:
+    """(Bq, rows) RotatE scores −Σ_k |q_k − e_k| of the query planes against
+    entity planes ``r0 : r0 + rows``, over ``half`` complex components in
+    128-lane chunks."""
+    dh = q_ref.shape[1] // 2
+    acc = None
+    for c in range(0, dh, LANES):
+        re, im = pl.ds(c, LANES), pl.ds(dh + c, LANES)
+        ents = pl.ds(r0, rows)
+        dr = q_ref[:, re][:, None, :] - e_ref[ents, re][None]  # (Bq, rows, 128)
+        di = q_ref[:, im][:, None, :] - e_ref[ents, im][None]
+        m = jnp.sqrt(dr * dr + di * di + 1e-12)
+        if c + LANES > half:  # padding lanes: exactly 0, not sqrt(1e-12)
+            lane = jax.lax.broadcasted_iota(jnp.int32, m.shape, 2)
+            m = jnp.where(lane < half - c, m, 0.0)
+        acc = m if acc is None else acc + m
+    return -jnp.sum(acc, axis=-1)
+
+
+def _fused_rank_cl1_kernel(
+    q_ref,      # (Bq, W) query planes
+    g_ref,      # (Bq, 1) gold score per query
+    f_ref,      # (Bq, F) known-true entity ids (pad −1; gold always present)
+    e_ref,      # (Be, W) entity planes
+    o_ref,      # (Bq, 1) int32 rank counts — revisited across j
+    *,
+    half: int,
+    block_e: int,
+    num_entities: int,
+):
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    bq = q_ref.shape[0]
+    rows = min(CL1_ROWS, block_e)
+
+    def chunk(k, acc):
+        r0 = pl.multiple_of(k * rows, rows)
+        s = _cl1_plane_scores(q_ref, e_ref, r0, rows, half)
+        col = j * block_e + r0 + jax.lax.broadcasted_iota(jnp.int32, (bq, rows), 1)
+        beats = _beats(s, col, g_ref, f_ref, num_entities)
+        return acc + jnp.sum(beats.astype(jnp.int32), axis=1, keepdims=True)
+
+    o_ref[...] += jax.lax.fori_loop(
+        0, block_e // rows, chunk, jnp.zeros((bq, 1), jnp.int32)
+    )
 
 
 def fused_rank_fwd(
@@ -140,9 +246,13 @@ def fused_rank_fwd(
     block_q: int = 8,
     block_e: int = 256,
     interpret: bool = True,
+    half: int = 0,
 ) -> jnp.ndarray:
     """Filtered rank counts: out[i] = Σ_e 1[score(q_i, e) > gold_i], with
     entities listed in ``filt[i]`` and padding rows excluded. Rank = out + 1.
+
+    For ``cl1``, ``q`` and ``ent`` are ``cl1_planes`` of rows with ``half``
+    complex components, and ``block_e`` divides by ``CL1_ROWS`` when larger.
     """
     b, d = q.shape
     e, _ = ent.shape
@@ -150,10 +260,21 @@ def fused_rank_fwd(
     block_q = min(block_q, b)
     block_e = min(block_e, e)
     assert b % block_q == 0 and e % block_e == 0, (b, e, block_q, block_e)
+    # the cl1 kernel scores its block in whole CL1_ROWS chunks
+    assert mode != "cl1" or block_e <= CL1_ROWS or block_e % CL1_ROWS == 0, block_e
     f = filt.shape[1]
-    kernel = functools.partial(
-        _fused_rank_kernel, mode=mode, block_e=block_e, num_entities=num_entities
-    )
+    name = None
+    if mode == "cl1":
+        kernel = functools.partial(
+            _fused_rank_cl1_kernel, half=half, block_e=block_e,
+            num_entities=num_entities,
+        )
+        name = "fused_rank_cl1"
+    else:
+        kernel = functools.partial(
+            _fused_rank_kernel, mode=mode, block_e=block_e,
+            num_entities=num_entities,
+        )
     return pl.pallas_call(
         kernel,
         # j (entity axis) is the minormost grid dim → the (i, 0) output block
@@ -168,4 +289,5 @@ def fused_rank_fwd(
         out_specs=pl.BlockSpec((block_q, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
         interpret=interpret,
+        name=name,
     )(q, gold, filt, ent)

@@ -251,14 +251,14 @@ def lp_gold_scores(q: jnp.ndarray, ent: jnp.ndarray, idx: jnp.ndarray, mode: str
 def _use_score_kernel(via_kernel: bool | None, mode: str) -> bool:
     if via_kernel is not None:
         return via_kernel
-    from repro.kernels.dispatch import COMPILED_BACKENDS, RANK_KERNEL_MODES
+    from repro.kernels.dispatch import COMPILED_BACKENDS, PAIRWISE_KERNEL_MODES
 
     # compiled Pallas backends route through the tiled kernel (write-once
     # tiles — safe on TPU and GPU) for the modes it lowers; CPU CI keeps the
     # numerically-identical jnp broadcast (interpret mode would be slower)
     return (
         jax.default_backend() in COMPILED_BACKENDS
-        and mode in RANK_KERNEL_MODES
+        and mode in PAIRWISE_KERNEL_MODES
     )
 
 

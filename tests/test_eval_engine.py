@@ -66,8 +66,7 @@ def test_fused_ranks_matches_ref(b, e, d, block_e, impl, mode):
     gold = scores[jnp.arange(b), jnp.asarray(gold_idx)]
     ref = np.asarray(fused_ranks_ref(q, ent, gold, jnp.asarray(filt), mode=mode))
     if impl == "pallas":
-        # the kernel itself, interpreted: dispatch keeps cl1 off it (it does
-        # not lower on TPU), but its tile math stays pinned for every mode
+        # the kernel itself, interpreted (cl1 on its [re | im] planes)
         out = fused_ranks_pallas_graph(
             q, ent, gold, jnp.asarray(filt), mode=mode, block_q=8,
             block_e=block_e, interpret=True,
@@ -184,9 +183,9 @@ def test_resolve_rank_impl(monkeypatch):
     assert resolve_rank_impl(None) in ("pallas", "xla")
     monkeypatch.setenv("REPRO_RANK_IMPL", "pallas")
     assert resolve_rank_impl(None) == "pallas"
-    # RotatE's half-width tiles do not lower on TPU: always the scan
-    assert resolve_rank_impl(None, "cl1") == "xla"
-    assert resolve_rank_impl("pallas", "cl1") == "xla"
+    # RotatE's cl1 ranks take the kernel too, on its [re | im] planes
+    assert resolve_rank_impl(None, "cl1") == "pallas"
+    assert resolve_rank_impl("pallas", "cl1") == "pallas"
     assert resolve_rank_impl("pallas", "dot") == "pallas"
     with pytest.raises(ValueError):
         resolve_rank_impl("tensorflow")

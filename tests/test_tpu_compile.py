@@ -4,12 +4,16 @@ Nothing runs here. The TPU compiler installed with JAX compiles for a
 ``v5e:2x2`` topology that is described, not attached, and refuses what the
 chip would refuse: primitives Mosaic cannot lower, slices that do not fit
 the tiling, kernels that overrun fast memory. Shapes are the paper's largest
-owner, Dbpedia (Tab. 2): 491,078 entities at d=100, 128 queries per batch.
+owner, Dbpedia (Tab. 2): 491,078 entities at d=100 (d=1,000 for the served
+RotatE owner), 128 queries per batch.
 
 The topology is described inside a fixture, never while a module is
 imported, so every pytest worker collects the same tests and only the
 worker running this file loads the TPU library.
 """
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -93,20 +97,101 @@ def test_pairwise_score_kernel_compiles(spec, mode):
     )
 
 
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="Mosaic refuses the [re | im] half-width lane slices "
-           "(\"Shape mismatch in input, indices and output\"); dispatch "
-           "sends cl1 to the XLA scan",
-)
 def test_fused_rank_kernel_cl1_compiles(spec):
-    jax.jit(
+    """RotatE's ranks at the served RotatE owner's size: 491,078 rows of
+    1,000 reals ([re | im]), 128 queries, the tier's 2,048-row block (the
+    kernel takes 1,024 rows of planes a step)."""
+    _assert_kernel_compiles(
         lambda q, e, g, f: fused_ranks_pallas_graph(
-            q, e, g, f, mode="cl1", block_q=8, block_e=512, interpret=False
+            q, e, g, f, mode="cl1", block_q=8, block_e=2048, interpret=False
+        ),
+        spec((B, 1000)), spec((E, 1000)), spec((B,)), spec((B, 4), jnp.int32),
+    )
+
+
+#: sha256 of the TransE serving programs at ``dbpedia.serve``'s shapes (the
+#: tier's rank and top-k programs, 8 and 64 rows), lowered and compiled for a
+#: described v5e, with metadata stripped (``_stripped``). Pinned when the
+#: fused-rank kernel gained its cl1 path, which left them unchanged; a change
+#: to these programs shows here and updates the digests on purpose.
+TRANSE_SERVING_HLO = {
+    ("rank", 8, "lowered"): "5136fc6196599c016a99d2cb7e34506deb2d057862fc635eaccd98a49fdaf7e9",
+    ("rank", 8, "compiled"): "0919d4fea3fa318d159c5aa1657119da3868f85ed924796c046a3ccf4072062f",
+    ("rank", 64, "lowered"): "4c8c1cd3fad01363a3fb1e69c08c48f1568d5d5bfe128dc349ded1f56d10a338",
+    ("rank", 64, "compiled"): "54305bb6e06324447cfeba179dc112e652dee97d7779fa9c67681e7d929126ee",
+    ("topk", 8, "lowered"): "d74b957444f45014403b3294044adc284c113538aba2b31ae30bd9c7592f8f88",
+    ("topk", 8, "compiled"): "aec649ad5b878b958d008471ffb8df434c7729626b59130b15af55b9572074c6",
+    ("topk", 64, "lowered"): "7ca43a195aab05ed3391273dca830a647b912b03189f87cb57b3db621353b38c",
+    ("topk", 64, "compiled"): "1858a49f5707c2fa85aed9299aba9cce6f79e007334b2142ec20c99e76cdd648",
+}
+
+
+def _stripped(text: str) -> str:
+    """HLO text without metadata: op metadata, MLIR locations and the
+    compiled module's file and stack-frame tables."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"\s*loc\([^\n]*\)", "", text)
+    text = re.sub(r"(?m)^#loc.*\n", "", text)
+    return re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n", text,
+                  flags=re.S)
+
+
+@pytest.fixture
+def no_locations(monkeypatch):
+    """Compile with no source locations anywhere: none in traced programs,
+    and Mosaic kernels serialized without debug info, so that a moved line
+    of source does not read as a changed program."""
+    from jax._src import tpu_custom_call
+    from jaxlib.mlir.passmanager import PassManager
+
+    real = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def lower(module, **kw):
+        with module.context:
+            PassManager.parse("builtin.module(strip-debuginfo)").run(
+                module.operation)
+        return real(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", lower)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    limit = jax.config.jax_traceback_in_locations_limit
+    full = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    yield
+    jax.config.update("jax_traceback_in_locations_limit", limit)
+    jax.config.update("jax_include_full_tracebacks_in_locations", full)
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+@pytest.mark.parametrize("kind", ["rank", "topk"])
+def test_transe_serving_programs_unchanged(spec, no_locations, kind, rows):
+    """``dbpedia.serve``'s rank and top-k programs (TransE d=100, the tier's
+    2,048-entity blocks, a 16-wide filter plus the gold id, k bucket 16)
+    lower and compile to the pinned HLO. The scoped-VMEM limit is raised
+    to 32 MiB: the l1 tile at 2,048 entities needs a little over the
+    compiler's default 16 MiB."""
+    from repro.kge.eval import _side_counts_jit
+    from repro.kge.models import KGEModel
+    from repro.serving.engine import _streaming_topk_jit
+
+    m = KGEModel("transe", E, R, D, margin=2.0)
+    p = {"ent": spec((E, D)), "rel": spec((R, D))}
+    i = spec((rows,), jnp.int32)
+    if kind == "rank":
+        lowered = _side_counts_jit.lower(
+            p, m, i, i, i, spec((rows, 17), jnp.int32), side="tail",
+            block_e=2048, impl="pallas",
         )
-    ).lower(
-        spec((B, D)), spec((E, D)), spec((B,)), spec((B, 4), jnp.int32)
-    ).compile()
+    else:
+        lowered = _streaming_topk_jit.lower(
+            p, m, i, i, spec((rows, 16), jnp.int32), k=16, block_e=2048
+        )
+    compiled = lowered.compile({"xla_tpu_scoped_vmem_limit_kib": "32768"})
+    for stage, text in (("lowered", lowered.as_text()),
+                        ("compiled", compiled.as_text())):
+        digest = hashlib.sha256(_stripped(text).encode()).hexdigest()
+        assert digest == TRANSE_SERVING_HLO[(kind, rows, stage)], stage
 
 
 @pytest.mark.xfail(
