@@ -257,31 +257,6 @@ def streaming_side_counts(
     return np.asarray(counts)
 
 
-def side_counts_dispatch(
-    params,
-    model: KGEModel,
-    h: jnp.ndarray,
-    r: jnp.ndarray,
-    t: jnp.ndarray,
-    filt: jnp.ndarray,
-    *,
-    side: str,
-    block_e: int = 512,
-    impl: Optional[str] = None,
-) -> jnp.ndarray:
-    """One ASYNC jitted dispatch of the side-count engine: device arrays in,
-    device array out, no host sync — the serving tier's batch call. Identical
-    math to ``streaming_side_counts`` (same jit, same impl resolution); the
-    caller materializes the result when it chooses (``jax.Array.is_ready``
-    polling lets batches complete out of band while new ones dispatch)."""
-    from repro.kernels.dispatch import resolve_rank_impl
-
-    return _side_counts_jit(
-        params, model, h, r, t, filt,
-        side=side, block_e=block_e, impl=resolve_rank_impl(impl),
-    )
-
-
 def streaming_rank_counts(
     params,
     model: KGEModel,

@@ -184,27 +184,58 @@ def _complex_table(params) -> jnp.ndarray:
     return jnp.concatenate([params["ent"], params["ent_im"]], axis=1)
 
 
+def lp_layout(m: KGEModel):
+    """(mode, row width) of the entity table a decomposable family scores
+    tail queries against (``lp_table``), or None for TransH/R/D."""
+    if m.family == "transe":
+        return ("l2" if m.norm_ord == 2 else "l1"), m.dim
+    if m.family == "distmult":
+        return "dot", m.dim
+    if m.family == "complex":
+        return "dot", 2 * m.dim
+    if m.family == "rotate":
+        return "cl1", m.dim
+    return None
+
+
+def lp_table(params, m: KGEModel) -> jnp.ndarray:
+    """The (E, width) entity table of ``lp_layout``."""
+    return _complex_table(params) if m.family == "complex" else params["ent"]
+
+
+def _complex_tail(hre, him, rre, rim) -> jnp.ndarray:
+    return jnp.concatenate([hre * rre - him * rim, him * rre + hre * rim], 1)
+
+
+def lp_tail_query(params, m: KGEModel, rows: jnp.ndarray, r: jnp.ndarray):
+    """(B, width) tail query of (h, r) from ``rows``, the head entities'
+    rows of ``lp_table`` — which the serving programs gather from the table
+    they keep staged. Reads only the relation tables of ``params``."""
+    if m.family == "transe":
+        return rows + params["rel"][r]
+    if m.family == "distmult":
+        return rows * params["rel"][r]
+    if m.family == "complex":
+        return _complex_tail(rows[:, : m.dim], rows[:, m.dim:],
+                             params["rel"][r], params["rel_im"][r])
+    d2 = rows.shape[-1] // 2  # rotate
+    hr, hi = rows[..., :d2], rows[..., d2:]
+    ph = params["rel"][r]
+    cr, ci = jnp.cos(ph), jnp.sin(ph)
+    return jnp.concatenate([hr * cr - hi * ci, hr * ci + hi * cr], 1)
+
+
 def lp_query_tails(params, m: KGEModel, h: jnp.ndarray, r: jnp.ndarray):
     """(query (B,d), entity table (E,d), mode) for tail ranking, or None."""
-    if m.family == "transe":
-        q = params["ent"][h] + params["rel"][r]
-        return q, params["ent"], ("l2" if m.norm_ord == 2 else "l1")
-    if m.family == "distmult":
-        return params["ent"][h] * params["rel"][r], params["ent"], "dot"
+    layout = lp_layout(m)
+    if layout is None:
+        return None
     if m.family == "complex":
-        hre, him = params["ent"][h], params["ent_im"][h]
-        rre, rim = params["rel"][r], params["rel_im"][r]
-        q = jnp.concatenate([hre * rre - him * rim, him * rre + hre * rim], 1)
-        return q, _complex_table(params), "dot"
-    if m.family == "rotate":
-        he = params["ent"][h]
-        d2 = he.shape[-1] // 2
-        hr, hi = he[..., :d2], he[..., d2:]
-        ph = params["rel"][r]
-        cr, ci = jnp.cos(ph), jnp.sin(ph)
-        q = jnp.concatenate([hr * cr - hi * ci, hr * ci + hi * cr], 1)
-        return q, params["ent"], "cl1"
-    return None
+        q = _complex_tail(params["ent"][h], params["ent_im"][h],
+                          params["rel"][r], params["rel_im"][r])
+    else:
+        q = lp_tail_query(params, m, params["ent"][h], r)
+    return q, lp_table(params, m), layout[0]
 
 
 def lp_query_heads(params, m: KGEModel, r: jnp.ndarray, t: jnp.ndarray):
@@ -234,7 +265,12 @@ def lp_gold_scores(q: jnp.ndarray, ent: jnp.ndarray, idx: jnp.ndarray, mode: str
     """Gather gold scores with the SAME expansion the tile kernel uses, so the
     gold entity's in-tile score differs from its gathered score only by fp
     noise (and the engine excludes gold via the filter row anyway)."""
-    e = ent[idx].astype(jnp.float32)
+    return lp_gold_rows(q, ent[idx], mode)
+
+
+def lp_gold_rows(q: jnp.ndarray, rows: jnp.ndarray, mode: str):
+    """``lp_gold_scores`` from the gold entities' (B, d) table rows."""
+    e = rows.astype(jnp.float32)
     q = q.astype(jnp.float32)
     if mode == "dot":
         return jnp.sum(q * e, axis=-1)

@@ -31,10 +31,15 @@ class KGECandidateRanker:
     candidates over a trained KGE model.
 
     Ranking goes through the streaming fused-rank engine
-    (``kernels.triple_score.fused_ranks``), candidate retrieval through a
-    blockwise ``lax.scan`` top-k merge — in both cases the (B, E) score
-    matrix never materializes, so a ranker over a 10⁶-entity table serves
-    from O(B·block_e) working memory per step.
+    (``kernels.triple_score``), candidate retrieval through a blockwise
+    ``lax.scan`` top-k merge — in both cases the (B, E) score matrix never
+    materializes, so a ranker over a 10⁶-entity table serves from
+    O(B·block_e) working memory per step. Both read the entity table its
+    ``TableVersion`` staged once, where the params live, as the serving
+    tier's replicas do. The ranker therefore holds its entity table twice
+    in device memory, the params as given and the staged copy: 0.25 GB
+    more for Dbpedia's TransE table, 2.01 GB for its RotatE one
+    (``TableVersion.staged_bytes``). ``swap`` replaces both.
 
     Per-request host work is O(B): the known-true filter is packed once at
     construction into a padded CSR ``FilterPack`` (pow-2 width, so the jits
@@ -57,7 +62,8 @@ class KGECandidateRanker:
             else FilterPack(known_triples, model.num_entities)
         )
         self._hr_t, self._rt_h = self.filters.hr_t, self.filters.rt_h
-        self._tv = TableVersion(params, model, self.filters, version=0)
+        self._tv = TableVersion(params, model, self.filters, version=0,
+                                block_e=block_e)
 
     @property
     def params(self):
@@ -75,7 +81,8 @@ class KGECandidateRanker:
 
         v = self._tv.version + 1 if version is None else int(version)
         self._tv = TableVersion(
-            params, self.model, self.filters, version=v, owner=self._tv.owner
+            params, self.model, self.filters, version=v, owner=self._tv.owner,
+            block_e=self.block_e,
         )
         return self._tv
 
@@ -105,18 +112,16 @@ class KGECandidateRanker:
 
     def rank_tails(self, h, r, t) -> np.ndarray:
         """Filtered rank of each gold tail t among all entities — (B,) int."""
-        from repro.kge.eval import streaming_side_counts
-
         h = self._check_ids("head entity", h, self.model.num_entities)
         t = self._check_ids("tail entity", t, self.model.num_entities)
         r = self._check_ids("relation", r, self.model.num_relations)
         self._check_query(h, r)
-        chunk = np.stack([h, r, t], axis=1)
-        counts = streaming_side_counts(
-            self.params, self.model, chunk, self.rank_filter(h, r, t),
-            side="tail", block_e=self.block_e, impl=self.impl,
+        counts = side_counts_dispatch(
+            self._tv.on(None), self.model, jnp.asarray(h), jnp.asarray(r),
+            jnp.asarray(t), jnp.asarray(self.rank_filter(h, r, t)),
+            entities=self._tv.entities, block_e=self.block_e, impl=self.impl,
         )
-        return counts + 1
+        return np.asarray(counts) + 1
 
     # ---- streaming top-k candidates --------------------------------------
     def topk_tails(self, h, r, k: int = 10, *, exclude_known: bool = True):
@@ -130,22 +135,26 @@ class KGECandidateRanker:
         else:
             filt = np.full((h_np.shape[0], 1), -1, np.int32)
         vals, ids = _streaming_topk_jit(
-            self.params, self.model, jnp.asarray(h_np), jnp.asarray(r_np),
-            jnp.asarray(filt), k=k, block_e=self.block_e,
+            self._tv.on(None), self.model, jnp.asarray(h_np),
+            jnp.asarray(r_np), jnp.asarray(filt), k=k,
+            entities=self._tv.entities, block_e=self.block_e,
         )
         return np.asarray(ids), np.asarray(vals)
 
 
-def _topk_scan(score_block, b, e, filt, *, k, block_e):
+def _topk_scan(score_block, blocks, b, e, filt, *, k, block_e):
     """Shared blockwise top-k merge: carry (vals, ids), fold in one entity
-    block per step. ``score_block(ids_block) → (B, Be)`` scores."""
-    be = min(block_e, e)
+    block per step. ``score_block(block, ids_block) → (B, Be)`` scores;
+    ``blocks`` (n_blocks, Be, …) are scanned with the ids (None: ids only,
+    in blocks of ``block_e``)."""
+    be = min(block_e, e) if blocks is None else blocks.shape[1]
     n_blocks = -(-e // be)
     cols = jnp.arange(n_blocks * be, dtype=jnp.int32).reshape(n_blocks, be)
 
-    def step(carry, cb):
+    def step(carry, inp):
         vals, ids = carry  # (B, k)
-        s = score_block(cb)  # (B, Be)
+        eb, cb = inp
+        s = score_block(eb, cb)  # (B, Be)
         excl = jnp.any(filt[:, :, None] == cb[None, None, :], axis=1)
         s = jnp.where(excl | (cb >= e)[None, :], -jnp.inf, s)
         allv = jnp.concatenate([vals, s], axis=1)
@@ -158,46 +167,105 @@ def _topk_scan(score_block, b, e, filt, *, k, block_e):
         jnp.full((b, min(k, e)), -jnp.inf, jnp.float32),
         jnp.full((b, min(k, e)), -1, jnp.int32),
     )
-    (vals, ids), _ = jax.lax.scan(step, init, cols)
+    (vals, ids), _ = jax.lax.scan(step, init, (blocks, cols))
     return vals, ids
 
 
-def streaming_topk_graph(params, model, h, r, filt, *, k, block_e):
-    """Filtered streaming top-k tails as a pure graph: the query projection
-    (``lp_query_tails``) is traced into the same program as the blockwise
-    scan, so a top-k dispatch is one jitted call with no eager gathers ahead
-    of it — the top-k counterpart of ``kge.eval.side_counts_graph``.
-    Families that decompose score (query, table block) tiles; the rest
-    (TransH/R/D) score every (h, r, candidate) triple of a block."""
-    from repro.kge.models import lp_query_tails, score_triples
+def streaming_topk_graph(tables, model, h, r, filt, *, k, entities, block_e):
+    """Filtered streaming top-k tails as a pure graph, against the tables a
+    ``TableVersion`` serves (``TableVersion.on``): the query projection is
+    traced into the same program as the blockwise scan, so a top-k
+    dispatch is one jitted call with no eager gathers ahead of it — the
+    top-k counterpart of ``served_counts_graph``. Decomposable
+    families take the query rows and each block of the scan from the staged
+    entity table (``entities`` real rows) as it is, by slice; TransH/R/D
+    score every (h, r, candidate) triple of a block from their tables."""
+    from repro.kernels.triple_score.ops import staged_blocks, unstage_rows
     from repro.kernels.triple_score.triple_score import _tile_scores
+    from repro.kge.models import lp_layout, lp_tail_query, score_triples
 
     b = h.shape[0]
-    qd = lp_query_tails(params, model, h, r)
-    if qd is not None:
-        q, table, mode = qd
-        e = table.shape[0]
+    layout = lp_layout(model)
+    if layout is not None:
+        mode, d = layout
+        staged = tables["staged"]
+        q = lp_tail_query(tables, model, unstage_rows(staged[h], mode, d), r)
+        q = q.astype(jnp.float32)
+        e = entities
+        _, sb = staged_blocks(e, d, mode, block_e)
+        blocks = staged.reshape(-1, sb, staged.shape[1])
 
-        def score_block(cb):
-            eb = table[jnp.clip(cb, 0, e - 1)]
-            return _tile_scores(q.astype(jnp.float32), eb.astype(jnp.float32), mode)
+        def score_block(eb, cb):
+            eb = unstage_rows(eb, mode, d).astype(jnp.float32)
+            return _tile_scores(q, eb, mode)
     else:
         e = model.num_entities
+        blocks = None
 
-        def score_block(cb):
+        def score_block(_, cb):
             ids = jnp.clip(cb, 0, e - 1)
             be = ids.shape[0]
             hh = jnp.repeat(h[:, None], be, axis=1).reshape(-1)
             rr = jnp.repeat(r[:, None], be, axis=1).reshape(-1)
             tt = jnp.tile(ids[None], (b, 1)).reshape(-1)
-            return score_triples(params, model, hh, rr, tt).reshape(b, be)
+            return score_triples(tables, model, hh, rr, tt).reshape(b, be)
 
-    return _topk_scan(score_block, b, e, filt, k=k, block_e=block_e)
+    return _topk_scan(score_block, blocks, b, e, filt, k=k, block_e=block_e)
 
 
 _streaming_topk_jit = jax.jit(
-    streaming_topk_graph, static_argnames=("model", "k", "block_e")
+    streaming_topk_graph, static_argnames=("model", "k", "entities", "block_e")
 )
+
+
+def served_counts_graph(tables, model, h, r, t, filt, *, entities, block_e,
+                        impl=None):
+    """Tail rank counts against the tables a ``TableVersion`` serves
+    (``TableVersion.on``) — the serving tier's and the ranker's rank
+    program. Decomposable families gather the query and gold rows from the
+    staged entity table (``entities`` real rows) and rank the table as it
+    is; TransH/R/D score triples from their published tables, as
+    ``kge.eval.side_counts_graph`` does."""
+    from repro.kernels.triple_score.ops import staged_ranks_graph, unstage_rows
+    from repro.kge.eval import generic_counts_graph
+    from repro.kge.models import (
+        lp_gold_rows, lp_layout, lp_tail_query, score_triples,
+    )
+
+    layout = lp_layout(model)
+    if layout is None:
+        gold = score_triples(tables, model, h, r, t)
+        return generic_counts_graph(tables, model, h, r, gold, filt,
+                                    side="tail", block_e=block_e)
+    mode, d = layout
+    staged = tables["staged"]
+    q = lp_tail_query(tables, model, unstage_rows(staged[h], mode, d), r)
+    gold = lp_gold_rows(q, unstage_rows(staged[t], mode, d), mode)
+    return staged_ranks_graph(q, staged, gold, filt, mode=mode,
+                              num_entities=entities, block_e=block_e,
+                              impl=impl)
+
+
+_served_counts_jit = jax.jit(
+    served_counts_graph,
+    static_argnames=("model", "entities", "block_e", "impl"),
+)
+
+
+def side_counts_dispatch(tables, model, h, r, t, filt, *, entities, block_e,
+                         impl: Optional[str] = None):
+    """One ASYNC jitted dispatch of ``served_counts_graph``: device arrays
+    in, device array out, no host sync — the serving tier's batch call and
+    the ranker's. The implementation is resolved here (host-side) so the
+    env overrides keep taking effect per call; the caller materializes the
+    result when it chooses (``jax.Array.is_ready`` polling lets batches
+    complete out of band while new ones dispatch)."""
+    from repro.kernels.dispatch import resolve_rank_impl
+
+    return _served_counts_jit(
+        tables, model, h, r, t, filt,
+        entities=entities, block_e=block_e, impl=resolve_rank_impl(impl),
+    )
 
 
 @dataclass

@@ -9,7 +9,7 @@ validated requests; ``step()`` coalesces the FIFO head into one query batch
 bucket and slices filters from the precomputed pow-2-width ``FilterPack``,
 so steady-state traffic hits a FIXED set of compiled programs — the tick
 engine's signature-bucket idiom applied to queries. Batches dispatch
-asynchronously (``kge.eval.side_counts_dispatch`` — device out, no host
+asynchronously (``serving.engine.side_counts_dispatch`` — device out, no host
 sync) and results are collected by non-blocking ``jax.Array.is_ready``
 polling, so new batches launch while old ones execute.
 
@@ -42,10 +42,11 @@ served / shed / failed — ``run_until_drained`` asserts
 ``served + shed + failed == submitted`` at every drain point.
 
 **Version hot-swap** — ``publish(params)`` builds an immutable
-``TableVersion`` (non-finite bitmask computed once), pre-stages it onto the
-replica ring with async ``device_put`` (zero-copy on the device already
-holding the committed params), and atomically flips the active pointer
-between batches. In-flight batches hold a reference to the version they
+``TableVersion`` (non-finite bitmask computed once), stages it onto every
+replica device (a ``device_put`` where the params live elsewhere, then the
+entity table laid out once in the layout the rank and top-k programs read,
+``stats["staged"]``), and atomically flips the active pointer between
+batches. In-flight batches hold a reference to the version they
 were dispatched on and finish (and retry) there — no traffic pause, no
 failed requests. Because a hot-swap can land between submit-time
 validation and dispatch, ``_dispatch`` re-checks every request against the
@@ -96,8 +97,11 @@ from repro.kernels.dispatch import (
     resolve_serve_impl,
     resolve_serve_replicas,
 )
-from repro.kge.eval import side_counts_dispatch
-from repro.serving.engine import _streaming_topk_jit
+from repro.serving.engine import (
+    _served_counts_jit,
+    _streaming_topk_jit,
+    side_counts_dispatch,
+)
 from repro.serving.tables import FilterPack, TableVersion, check_id_range
 
 
@@ -281,6 +285,8 @@ class KGEServingTier:
             "breaker_open": 0, "breaker_close": 0,
             "batches": 0, "published": 0, "publish_errors": 0,
             "warmed": 0,
+            # entity tables staged: one per (version, replica device)
+            "staged": 0,
             # why a batched coalesce stopped growing, one count per batch:
             # the next request was of the other kind or top-k bucket, would
             # pass max_batch, or the queue ran out
@@ -324,20 +330,23 @@ class KGEServingTier:
         """Publish a new table version and atomically make it active.
 
         Builds the immutable ``TableVersion`` (one on-device finiteness
-        reduction per table), pre-stages it onto every replica device with
-        asynchronous ``device_put`` (zero-copy where the params are already
-        committed — the owner's sticky home), then flips the active
-        pointer. Batches dispatched before the flip complete on the old
-        version; batches dispatched after serve the new one. No pause."""
+        reduction per table), stages it onto every replica device — the
+        params committed there (zero-copy where they already are: the
+        owner's sticky home) and the entity table staged once for the
+        serving programs — then flips the active pointer. Batches
+        dispatched before the flip complete on the old version; batches
+        dispatched after serve the new one. No pause."""
         with self._publish_lock:
             v = (
                 (self._active.version + 1 if self._active is not None else 0)
                 if version is None else int(version)
             )
             tv = TableVersion(params, self.model, self.filters,
-                              version=v, owner=self.owner)
+                              version=v, owner=self.owner,
+                              block_e=self.block_e)
             for rep in self.replicas:
                 tv.on(rep.device)
+            self.stats["staged"] += tv.staged
             self._warm(tv)
             self._active = tv
             self.stats["published"] += 1
@@ -380,14 +389,15 @@ class KGEServingTier:
                         (h, r, t, filt), rep.device
                     )
                     side_counts_dispatch(
-                        ptab, self.model, dh, dr, dt, df, side="tail",
-                        block_e=self.block_e, impl=self.rank_impl,
+                        ptab, self.model, dh, dr, dt, df,
+                        entities=tv.entities, block_e=self.block_e,
+                        impl=self.rank_impl,
                     )
                 else:
                     dh, dr, df = jax.device_put((h, r, filt), rep.device)
                     _streaming_topk_jit(
                         ptab, self.model, dh, dr, df, k=kb,
-                        block_e=self.block_e,
+                        entities=tv.entities, block_e=self.block_e,
                     )
                 self._warmed.add(sig)
                 self.stats["warmed"] += 1
@@ -692,14 +702,15 @@ class KGEServingTier:
             if kind == "rank":
                 dh, dr, dt, df = dev_in
                 counts = side_counts_dispatch(
-                    ptab, self.model, dh, dr, dt, df, side="tail",
+                    ptab, self.model, dh, dr, dt, df, entities=tv.entities,
                     block_e=self.block_e, impl=self.rank_impl,
                 )
                 out: Tuple = (counts,)
             else:
                 dh, dr, df = dev_in
                 out = _streaming_topk_jit(
-                    ptab, self.model, dh, dr, df, k=kb, block_e=self.block_e,
+                    ptab, self.model, dh, dr, df, k=kb, entities=tv.entities,
+                    block_e=self.block_e,
                 )
         rep.inflight += 1
         rep.dispatched += 1
@@ -954,6 +965,4 @@ def serving_program_cache_size() -> int:
     steady-state traffic of ANY mix of batch sizes within the bucket set —
     continuous batching is only a win if padded buckets actually stop
     recompilation."""
-    from repro.kge.eval import _side_counts_jit
-
-    return _side_counts_jit._cache_size() + _streaming_topk_jit._cache_size()
+    return _served_counts_jit._cache_size() + _streaming_topk_jit._cache_size()

@@ -6,7 +6,7 @@ a dense float64 NumPy filtered top-k written out per family here, for every
 decomposable family and one generic (TransH) — ids exact outside f32
 near-ties, scores to f32 tolerance, tier bit-equal to the ranker.
 
-Mechanism: the query projection (``lp_query_tails``) only ever runs while
+Mechanism: the query projection (``lp_tail_query``) only ever runs while
 the top-k program traces — never eagerly on concrete arrays, at warm-up,
 steady-state tier batches or ranker calls."""
 import jax
@@ -108,19 +108,20 @@ def test_topk_parity_vs_dense_numpy(family, norm_ord):
 
 
 def test_topk_query_projection_only_traced(monkeypatch):
-    """``lp_query_tails`` on the top-k path sees tracers only: the tier's
+    """``lp_tail_query`` on the top-k path sees tracers only: the tier's
     warm-up, its steady-state batches and the ranker all dispatch one
-    program that projects the query inside it."""
+    program that projects the query inside it, from the head rows it
+    gathers out of the staged table."""
     traced = []
-    real = kge_models.lp_query_tails
+    real = kge_models.lp_tail_query
 
-    def only_traced(params, m, h, r):
-        if not all(isinstance(x, jax.core.Tracer) for x in (h, r)):
-            raise AssertionError("lp_query_tails called eagerly on the top-k path")
-        traced.append(h.shape)
-        return real(params, m, h, r)
+    def only_traced(params, m, rows, r):
+        if not all(isinstance(x, jax.core.Tracer) for x in (rows, r)):
+            raise AssertionError("lp_tail_query called eagerly on the top-k path")
+        traced.append(rows.shape)
+        return real(params, m, rows, r)
 
-    monkeypatch.setattr(kge_models, "lp_query_tails", only_traced)
+    monkeypatch.setattr(kge_models, "lp_tail_query", only_traced)
     m = KGEModel("transe", E, R, D)
     params = init_kge(jax.random.PRNGKey(6), m)
     known = _tri(400, seed=210)

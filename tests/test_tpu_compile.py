@@ -3,7 +3,9 @@
 Nothing runs here. The TPU compiler installed with JAX compiles for a
 ``v5e:2x2`` topology that is described, not attached, and refuses what the
 chip would refuse: primitives Mosaic cannot lower, slices that do not fit
-the tiling, kernels that overrun fast memory. Shapes are the paper's largest
+the tiling, kernels that overrun fast memory. The compiled serving programs
+also show which arrays they write and in which layout they take the staged
+entity table. Shapes are the paper's largest
 owner, Dbpedia (Tab. 2): 491,078 entities at d=100 (d=1,000 for the served
 RotatE owner), 128 queries per batch.
 
@@ -112,17 +114,18 @@ def test_fused_rank_kernel_cl1_compiles(spec):
 #: sha256 of the TransE serving programs at ``dbpedia.serve``'s shapes (the
 #: tier's rank and top-k programs, 8 and 64 rows), lowered and compiled for a
 #: described v5e, with metadata stripped (``_stripped``). Pinned when the
-#: fused-rank kernel gained its cl1 path, which left them unchanged; a change
-#: to these programs shows here and updates the digests on purpose.
+#: programs came to read the entity table staged once per published version
+#: (491,520 x 128, row-major) instead of the published table; a change to
+#: these programs shows here and updates the digests on purpose.
 TRANSE_SERVING_HLO = {
-    ("rank", 8, "lowered"): "5136fc6196599c016a99d2cb7e34506deb2d057862fc635eaccd98a49fdaf7e9",
-    ("rank", 8, "compiled"): "0919d4fea3fa318d159c5aa1657119da3868f85ed924796c046a3ccf4072062f",
-    ("rank", 64, "lowered"): "4c8c1cd3fad01363a3fb1e69c08c48f1568d5d5bfe128dc349ded1f56d10a338",
-    ("rank", 64, "compiled"): "54305bb6e06324447cfeba179dc112e652dee97d7779fa9c67681e7d929126ee",
-    ("topk", 8, "lowered"): "d74b957444f45014403b3294044adc284c113538aba2b31ae30bd9c7592f8f88",
-    ("topk", 8, "compiled"): "aec649ad5b878b958d008471ffb8df434c7729626b59130b15af55b9572074c6",
-    ("topk", 64, "lowered"): "7ca43a195aab05ed3391273dca830a647b912b03189f87cb57b3db621353b38c",
-    ("topk", 64, "compiled"): "1858a49f5707c2fa85aed9299aba9cce6f79e007334b2142ec20c99e76cdd648",
+    ("rank", 8, "lowered"): "3d96805f0c3706b9cc5f93d3eae10644a982fbf9147df5b1febf8c1d5f25c904",
+    ("rank", 8, "compiled"): "7e149c94446382c2bd8df476c2e62ab2b4947e8861c63a6bac289d5c3962f2a8",
+    ("rank", 64, "lowered"): "5f14b840d73d49c357b769089f229f5c7dd5a97c22960f551e5892411e010f0c",
+    ("rank", 64, "compiled"): "27d10e290b1e7949c677002a5e549b9b0735f5e7eeb0b89e48f7211f2763082e",
+    ("topk", 8, "lowered"): "3b94475272180939443ae715d9ea4b6906c5f93cee112cf9d8fa9fa2faa54736",
+    ("topk", 8, "compiled"): "d1c85b55c847871d53c64f3c557135e1e5d78746c24073090652155843f16c6a",
+    ("topk", 64, "lowered"): "fdd34eb1bda01c89493d8b2175168559fd02b5017fe3b5fa7bc390e9ceacbb4b",
+    ("topk", 64, "compiled"): "43f7ff1c6939b23aa4c190c6045af1cd1f046f9d591d99a7110aa7a85a2c2f30",
 }
 
 
@@ -163,35 +166,128 @@ def no_locations(monkeypatch):
     jax.config.update("jax_include_full_tracebacks_in_locations", full)
 
 
+#: rows of Dbpedia's staged entity table: whole 2,048-row blocks
+STAGED_ROWS = 491_520
+
+
+def _served_program(spec, family, kind, rows):
+    """A serving program at ``dbpedia.serve``'s (TransE, d=100) or
+    ``dbpedia.rotate.serve``'s (RotatE, d=1,000) shapes, lowered for a
+    described v5e: the tier's 2,048-entity blocks, a 16-wide filter plus
+    the gold id, k bucket 16, the entity table as the tier stages it."""
+    from repro.kge.models import KGEModel
+    from repro.serving.engine import _served_counts_jit, _streaming_topk_jit
+
+    d, lanes, rel = {"transe": (D, 128, D), "rotate": (1000, 1024, 500)}[family]
+    m = KGEModel(family, E, R, d, margin=2.0)
+    tables = {"staged": spec((STAGED_ROWS, lanes)), "rel": spec((R, rel))}
+    i = spec((rows,), jnp.int32)
+    if kind == "rank":
+        return _served_counts_jit.lower(
+            tables, m, i, i, i, spec((rows, 17), jnp.int32), entities=E,
+            block_e=2048, impl="pallas",
+        )
+    return _streaming_topk_jit.lower(
+        tables, m, i, i, spec((rows, 16), jnp.int32), k=16, entities=E,
+        block_e=2048,
+    )
+
+
+#: the scoped-VMEM limit the serving programs compile with: the l1 tile at
+#: 2,048 entities needs a little over the compiler's default 16 MiB
+VMEM_32M = {"xla_tpu_scoped_vmem_limit_kib": "32768"}
+
+
 @pytest.mark.parametrize("rows", [8, 64])
 @pytest.mark.parametrize("kind", ["rank", "topk"])
 def test_transe_serving_programs_unchanged(spec, no_locations, kind, rows):
-    """``dbpedia.serve``'s rank and top-k programs (TransE d=100, the tier's
-    2,048-entity blocks, a 16-wide filter plus the gold id, k bucket 16)
-    lower and compile to the pinned HLO. The scoped-VMEM limit is raised
-    to 32 MiB: the l1 tile at 2,048 entities needs a little over the
-    compiler's default 16 MiB."""
-    from repro.kge.eval import _side_counts_jit
-    from repro.kge.models import KGEModel
-    from repro.serving.engine import _streaming_topk_jit
-
-    m = KGEModel("transe", E, R, D, margin=2.0)
-    p = {"ent": spec((E, D)), "rel": spec((R, D))}
-    i = spec((rows,), jnp.int32)
-    if kind == "rank":
-        lowered = _side_counts_jit.lower(
-            p, m, i, i, i, spec((rows, 17), jnp.int32), side="tail",
-            block_e=2048, impl="pallas",
-        )
-    else:
-        lowered = _streaming_topk_jit.lower(
-            p, m, i, i, spec((rows, 16), jnp.int32), k=16, block_e=2048
-        )
-    compiled = lowered.compile({"xla_tpu_scoped_vmem_limit_kib": "32768"})
+    """``dbpedia.serve``'s rank and top-k programs lower and compile to the
+    pinned HLO."""
+    lowered = _served_program(spec, "transe", kind, rows)
+    compiled = lowered.compile(VMEM_32M)
     for stage, text in (("lowered", lowered.as_text()),
                         ("compiled", compiled.as_text())):
         digest = hashlib.sha256(_stripped(text).encode()).hexdigest()
         assert digest == TRANSE_SERVING_HLO[(kind, rows, stage)], stage
+
+
+#: sha256 of the federation tick's rank program, ``_side_counts_jit``, at
+#: Dbpedia's TransE shapes (8 and 64 rows, 2,048-entity blocks, a 16-wide
+#: filter plus the gold id), lowered and compiled for a described v5e with
+#: metadata stripped. The tick's table changes every tick, so its program
+#: stages the table inline, per call, and these digests stay as they were
+#: when the serving tier read the published table through this program.
+TICK_RANK_HLO = {
+    (8, "lowered"): "5136fc6196599c016a99d2cb7e34506deb2d057862fc635eaccd98a49fdaf7e9",
+    (8, "compiled"): "0919d4fea3fa318d159c5aa1657119da3868f85ed924796c046a3ccf4072062f",
+    (64, "lowered"): "4c8c1cd3fad01363a3fb1e69c08c48f1568d5d5bfe128dc349ded1f56d10a338",
+    (64, "compiled"): "54305bb6e06324447cfeba179dc112e652dee97d7779fa9c67681e7d929126ee",
+}
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_tick_rank_program_unchanged(spec, no_locations, rows):
+    """The tick's TransE rank program lowers and compiles to the pinned
+    HLO."""
+    from repro.kge.eval import _side_counts_jit
+    from repro.kge.models import KGEModel
+
+    m = KGEModel("transe", E, R, D, margin=2.0)
+    p = {"ent": spec((E, D)), "rel": spec((R, D))}
+    i = spec((rows,), jnp.int32)
+    lowered = _side_counts_jit.lower(
+        p, m, i, i, i, spec((rows, 17), jnp.int32), side="tail",
+        block_e=2048, impl="pallas",
+    )
+    compiled = lowered.compile(VMEM_32M)
+    for stage, text in (("lowered", lowered.as_text()),
+                        ("compiled", compiled.as_text())):
+        digest = hashlib.sha256(_stripped(text).encode()).hexdigest()
+        assert digest == TICK_RANK_HLO[(rows, stage)], stage
+
+
+#: an HLO instruction and the leading extent of its result
+_INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = f32\[(\d+)[,\]][^ ]* ([\w-]+)\(")
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+@pytest.mark.parametrize("kind", ["rank", "topk"])
+@pytest.mark.parametrize("family", ["transe", "rotate"])
+def test_served_programs_write_no_table_sized_array(spec, monkeypatch, family,
+                                                    kind, rows):
+    """The rank and top-k programs read the staged table as it is: no
+    instruction of the compiled program writes an array of the table's
+    rows (a copy, pad, transpose or fusion of ``f32[>=E, ...]``), and the
+    program takes the staged table row-major."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    compiled = _served_program(spec, family, kind, rows).compile(VMEM_32M)
+    written = [
+        line.strip()[:120] for line in compiled.as_text().splitlines()
+        if (m := _INSTR.match(line)) and int(m.group(1)) >= E
+        and m.group(2) not in ("parameter", "get-tuple-element", "bitcast")
+    ]
+    assert not written, written
+    staged = compiled.input_formats[0][0]["staged"]
+    assert staged.layout.major_to_minor == (0, 1)
+
+
+@pytest.mark.parametrize("family", ["transe", "rotate"])
+def test_staging_writes_the_table_row_major(spec, family):
+    """Staging Dbpedia's entity table compiles for the v5e, and writes it
+    row-major in whole blocks and lane tiles: the layout the serving
+    programs take without a copy."""
+    from repro.kge.models import KGEModel
+    from repro.serving.tables import _stage
+
+    d, lanes, rel = {"transe": (D, 128, D), "rotate": (1000, 1024, 500)}[family]
+    m = KGEModel(family, E, R, d)
+    compiled = _stage.lower(
+        {"ent": spec((E, d)), "rel": spec((R, rel))}, m, 2048
+    ).compile()
+    (out,) = compiled.out_info if isinstance(compiled.out_info, tuple) else (
+        compiled.out_info,)
+    assert out.shape == (STAGED_ROWS, lanes)
+    assert compiled.output_formats.layout.major_to_minor == (0, 1)
 
 
 @pytest.mark.xfail(
